@@ -47,8 +47,9 @@ CHECK_THRESHOLD = 2.0  # >2x slower us_per_call fails --check
 BYTES_THRESHOLD = 1.1  # >10% more bytes_moved_ratio fails --check (exact metric)
 
 # Absolute gates (fresh run vs a fixed bound, no committed baseline
-# needed): the one-launch archival bench must KEEP its structural claim —
-# at most one kernel launch per K-stripe batch — and both entropy benches
+# needed): the batched archival bench must KEEP its structural claim —
+# three kernels (histogram, coder, seal) per K-stripe batch, whatever K
+# is — and both entropy benches
 # must hold the two-phase-encode win (PR 9) from both sides: wall-clock
 # ceilings and exactness, plus vs-host floors set from measured
 # CPU-interpret runs (entropy ~0.53-0.60, fused ~0.45-0.55, with +-15%
@@ -67,7 +68,7 @@ ABS_GATES = {
         ("exact_recip", "floor", 1.0),
     ),
     "entropy_seal_fused": (
-        ("launches", "ceiling", 1.0),
+        ("launches", "ceiling", 3.0),
         ("launches_per_stripe", "ceiling", 1.0),
         ("us_per_stripe", "ceiling", 22000.0),
         ("vs_host_speed", "floor", 0.3),
@@ -294,6 +295,9 @@ def main() -> None:
 
     from benchmarks import kernels_bench, paper_tables
     from benchmarks.common import fmt_rows
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     quick = os.environ.get("BENCH_FULL", "0") != "1"
     suites = [

@@ -51,9 +51,10 @@ Durability loop (scrub -> rebuild -> retire, ``core/archival/scrub.py``):
      records rewritten, retired bodies dropped) — only after that is the
      stripe's key/nonce material recycled.
 
-With the whole codes -> entropy -> pack -> ChaCha20 -> parity chain fused
-into one launch nothing round-trips the host OR HBM mid-chain; only disk
-I/O and O(1) manifest metadata (lengths, KEM polys, nonces, salience
+With the whole codes -> entropy -> pack -> ChaCha20 -> parity chain in one
+device program per stripe batch nothing round-trips the host mid-chain (the
+packed streams pass through HBM between the coder and seal kernels); only
+disk I/O and O(1) manifest metadata (lengths, KEM polys, nonces, salience
 descriptors) are host-side, and they cover *sealed, compressed* data — the paper's
 data-movement thesis in BOTH directions: ingest moves compressed bytes,
 retrieval moves only the planned shard subset (the ``retrieval`` bench
@@ -67,9 +68,10 @@ Granularities and seams:
 
 * ``archive_stripe`` / ``restore_stripe`` — the batched hot path.  All S
   shards of a stripe are entropy-coded, packed, ChaCha-sealed, and
-  parity-coded in ONE fused Pallas launch (``repro.kernels.fused``); only
-  the tiny per-shard KEM runs outside the kernel.  ``seal_payload_stripes``
-  is the K-stripe batched entry (one launch per homogeneous stripe group).
+  parity-coded in one device program (``repro.kernels.fused``: three
+  Pallas kernels with XLA glue); only the tiny per-shard KEM runs outside
+  it.  ``seal_payload_stripes`` is the K-stripe batched entry (one
+  dispatch per homogeneous stripe group).
   ``use_pallas=False`` dispatches the staged jnp reference instead
   (bit-identical outputs).
 * ``restore_stripe_payloads`` — the retrieval datapath below the neural
@@ -141,6 +143,7 @@ Perfetto-loadable trace via ``repro.obs.export``.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -162,6 +165,7 @@ from repro.core.crypto.hybrid import (
     seal,
     unseal,
 )
+from repro.kernels import stack_rows
 from repro.kernels.entropy import ops as entropy_ops
 from repro.kernels.fused import ops as fused_ops
 from repro.kernels.seal import ops as seal_ops
@@ -328,11 +332,17 @@ def encode_gop_payload(
     so ingest layers (``repro.distributed.archival.StripeCoalescer``) can
     encode GOPs as they arrive and defer sealing until a full stripe exists.
     """
-    frame_codes, recons = encode_gop(
-        codec_params, cfg.codec, frames, n_layers=cfg.n_layers
-    )
+    frame_codes, recons = _encode_gop_device(codec_params, frames, cfg)
     flat, manifest = _flatten_codes(frame_codes)
     return flat, dict(manifest, frames_shape=tuple(frames.shape)), recons
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _encode_gop_device(codec_params, frames, cfg: ArchiveConfig):
+    """The codec half of ``encode_gop_payload`` as ONE device program: run
+    op by op, a GOP is hundreds of small launches, each compiled on first
+    use."""
+    return encode_gop(codec_params, cfg.codec, frames, n_layers=cfg.n_layers)
 
 
 def entropy_encode_payloads(
@@ -458,10 +468,11 @@ def _bill_ingest(stripe, manifests: List[Dict], parity: Optional[Dict]) -> None:
 
 def _assemble_stripe(stripe, mats, manifests: List[Dict]) -> StripeArchive:
     """Wrap a SealedStripe + its KEM material as a ``StripeArchive``."""
+    bodies = stripe.bodies()
     blocks = [
         ArchivedBlock(
             SealedBlock(
-                m.kem_c1, m.kem_c2, m.nonce, stripe.body(s), stripe.n_words[s]
+                m.kem_c1, m.kem_c2, m.nonce, bodies[s], stripe.n_words[s]
             ),
             manifests[s],
         )
@@ -612,10 +623,9 @@ def seal_payload_stripes(
     stripes / manifests / keys are per-stripe lists; ``pad_rows`` is None,
     an int, or a per-stripe sequence (same re-bucketing semantics as the
     singular).  For ``codec_name="rans"`` the whole batch goes through the
-    one-launch fused kernel (``repro.kernels.fused``): homogeneous stripes
-    share ONE launch with K stripes on the batch axis, so per-launch
-    dispatch amortizes K-fold and the packed streams never visit HBM
-    between entropy and seal.  ``fused_fn`` overrides the batched launch
+    fused write program (``repro.kernels.fused``): homogeneous stripes
+    share ONE dispatch with K stripes on the batch axis, so per-dispatch
+    cost amortizes K-fold.  ``fused_fn`` overrides the batched launch
     (the sharded path passes ``entropy_seal_stripes`` with a shard_map'd
     ``core_fn``).  Host codecs fall back to the per-stripe chained path.
     Outputs are bit-identical to mapping ``seal_payload_stripe`` — and,
@@ -708,7 +718,7 @@ def archive_stripe(
     entropy_fn=None,
     fused_fn=None,
 ) -> Tuple[StripeArchive, List[jax.Array]]:
-    """Archive S GOPs as one parity stripe: codes -> one-launch entropy+seal.
+    """Archive S GOPs as one parity stripe: codes -> fused entropy+seal.
 
     frames_list: S clips, each (T, B, H, W, 3) — one per storage shard.
     ``use_pallas=False`` runs the staged jnp references instead
@@ -839,13 +849,9 @@ def restore_stripe_payloads(
         int(em.get("n_comp", b.manifest["n_i8"]))
         for b, em in zip(sub, emetas)
     )
-    R = seal_ops.pad_rows_for(max(n_words))
-    sealed = jnp.stack(
-        [
-            jnp.pad(b.sealed.body, (0, R * 128 - n)).reshape(R, 128)
-            for b, n in zip(sub, n_words)
-        ]
-    )
+    # pow2 row buckets: one unseal program per bucket, not per stripe
+    R = seal_ops.bucket_rows_for(max(n_words))
+    sealed = stack_rows([b.sealed.body for b in sub], R)
     packed = seal_ops.SealedStripe(sealed, None, None, n_words, n_i8)
     # recompute parity in the mode the stripe was actually sealed with (the
     # stored parity dict is ground truth), not whatever the caller's cfg
@@ -1087,12 +1093,7 @@ def recompute_stripe_parity(
             f"shard body of {max(n_words)} words exceeds the stripe's "
             f"seal-time pad_to={pad_to}"
         )
-    sealed = jnp.stack(
-        [
-            jnp.pad(b.sealed.body, (0, pad_to - n)).reshape(R, 128)
-            for b, n in zip(stripe.blocks, n_words)
-        ]
-    )
+    sealed = stack_rows([b.sealed.body for b in stripe.blocks], R)
     packed = seal_ops.SealedStripe(sealed, None, None, n_words, n_words)
     mode = "raid6" if "q" in parity else "raid5"
     fn = unseal_fn or seal_ops.unseal_stripe

@@ -28,10 +28,10 @@ Key structural facts encoded:
     syndrome bytes for the cross-shard compare, while a host-side scrub
     must move every sealed body over the host link;
   * per-launch dispatch overhead is NOT a per-stripe term on the on-device
-    path: the one-launch archival kernel (``repro.kernels.fused``) runs
-    entropy + pack + seal + parity as a single launch and batches K
-    coalesced stripes per launch, so fixed dispatch cost amortizes across
-    K stripes (launches/stripe = 1/K; the chained path paid 2 per stripe).
+    path: the fused archival program (``repro.kernels.fused``) runs
+    entropy + pack + seal + parity as one dispatch and batches K coalesced
+    stripes per dispatch, so fixed dispatch cost amortizes across K
+    stripes (dispatches/stripe = 1/K; three kernels per dispatch).
     The model therefore keeps dispatch folded into the per-byte compute
     rates instead of charging a per-stripe constant.
 

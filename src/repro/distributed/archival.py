@@ -22,11 +22,11 @@ seal kernel (``repro.kernels.seal``) over that axis:
     carrying the *global* shard index, so Q partials are globally correct
     before the reduce.)
 
-``entropy_seal_sharded`` is the one-launch twin: the FUSED entropy+seal
-kernel (``repro.kernels.fused`` — rANS + pack + raw-skip + ChaCha20 +
-parity in a single launch, K stripes batched per launch) shard_maps the
-same way, so the rans write path needs exactly one local launch per mesh
-shard per stripe batch, with the identical parity-reduce story.  The
+``entropy_seal_sharded`` is the fused write program's twin
+(``repro.kernels.fused`` — rANS + pack + raw-skip + ChaCha20 + parity, K
+stripes batched per dispatch) shard_mapped the same way, so the rans write
+path runs one local program per mesh shard per stripe batch, with the
+identical parity-reduce story.  The
 chained ``seal_stripe_sharded`` / ``entropy_encode_sharded`` pair stays
 the decode-side and host-codec path.
 
@@ -52,25 +52,12 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # JAX >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map with the replication check off, across jax versions
-    (``check_vma`` on >= 0.6, ``check_rep`` before)."""
-    try:
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return _shard_map_raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """shard_map with the replication check off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 from repro.core.archival.pipeline import (
     ArchiveConfig,
@@ -84,7 +71,7 @@ from repro.core.archival.pipeline import (
     seal_payload_stripes_finalize,
 )
 from repro.core.crypto import rlwe
-from repro.kernels import use_interpret
+from repro.kernels import host_prefixes, stack_rows, use_interpret
 from repro.kernels.entropy import ops as entropy_ops
 from repro.kernels.entropy.rans import PROB_SCALE
 from repro.kernels.fused import ops as fused_ops
@@ -243,20 +230,16 @@ def unseal_stripe_sharded(stripe: SealedStripe, keys, nonces, *, mesh: Mesh,
         mesh, axis, parity, True, use_pallas, use_interpret(interpret)
     )
     outs = core(*args)
-    codes = outs[0][:S]
     p = outs[1] if parity != "none" else None
     q = outs[2] if parity == "raid6" else None
-    flats = [
-        codes[s].reshape(-1)[: stripe.n_i8[s]] for s in range(S)
-    ]
-    return flats, p, q
+    return host_prefixes(outs[0][:S], stripe.n_i8), p, q
 
 
-# --------------------------------------------- sharded one-launch archival
+# ------------------------------------------------ sharded fused archival
 @functools.lru_cache(maxsize=None)
 def _sharded_fused_core(mesh: Mesh, axis: str, s_loc: int, parity: str,
-                        use_pallas: bool, interpret: bool, division: str):
-    """jit'd shard_map'd one-launch entropy+seal core, cached per (mesh,
+                        use_pallas: bool, interpret: bool):
+    """jit'd shard_map'd fused entropy+seal core, cached per (mesh,
     local shard count, mode).
 
     Inputs arrive regrouped as (K, S_pad, ...) — stripes on axis 0, stripe
@@ -285,8 +268,7 @@ def _sharded_fused_core(mesh: Mesh, axis: str, s_loc: int, parity: str,
         kw = {"interpret": interpret} if use_pallas else {}
         sealed, nw, p, q = fn(
             flat(codes), flat(n_valid), flat(keys), flat(nonces),
-            flat(q_coef), n_shards=s_loc, parity=parity, division=division,
-            **kw,
+            flat(q_coef), n_shards=s_loc, parity=parity, **kw,
         )
         outs = [
             sealed.reshape((K, s_loc) + sealed.shape[1:]),
@@ -311,9 +293,8 @@ def _sharded_fused_core(mesh: Mesh, axis: str, s_loc: int, parity: str,
 def entropy_seal_sharded(codes, n_valid, keys, nonces, q_coef, *,
                          mesh: Mesh, axis: str = "data", n_shards: int,
                          parity: str = "raid6", use_pallas: bool = True,
-                         interpret: Optional[bool] = None,
-                         division: str = "divide"):
-    """Sharded twin of the fused one-launch core (same array outputs).
+                         interpret: Optional[bool] = None):
+    """Sharded twin of the fused write core (same array outputs).
 
     Drop-in ``core_fn`` for ``fused_ops.entropy_seal_stripes`` (bake
     ``mesh``/``axis`` with ``functools.partial``; the batching layer
@@ -335,8 +316,7 @@ def entropy_seal_sharded(codes, n_valid, keys, nonces, q_coef, *,
         return jnp.pad(a, pad)
 
     core = _sharded_fused_core(
-        mesh, axis, s_pad // D, parity, use_pallas,
-        use_interpret(interpret), division,
+        mesh, axis, s_pad // D, parity, use_pallas, use_interpret(interpret)
     )
     outs = core(*(regroup(a) for a in (codes, n_valid, keys, nonces, q_coef)))
     sealed = outs[0][:, :n_shards].reshape((B,) + outs[0].shape[2:])
@@ -404,7 +384,7 @@ def _sharded_entropy_core(mesh: Mesh, axis: str, decode: bool,
     else:
         fn = _shard_map(
             local_encode, mesh=mesh,
-            in_specs=(P(axis), P(axis)), out_specs=(P(axis),) * 4,
+            in_specs=(P(axis), P(axis)), out_specs=(P(axis),) * 5,
         )
     return jax.jit(fn)
 
@@ -480,7 +460,7 @@ def archive_stripe_sharded(
     axis: str = "data",
     use_pallas: bool = True,
 ) -> Tuple[StripeArchive, List[jax.Array]]:
-    """``archive_stripe`` with the one-launch entropy+seal kernel
+    """``archive_stripe`` with the fused entropy+seal program
     shard_map'd over ``mesh``: each mesh shard entropy-codes, packs, seals
     and parity-folds its own slice of the stripe (the CSD-array mapping)
     in ONE local launch — codes -> rANS -> pack -> ChaCha20 -> parity with
@@ -930,13 +910,7 @@ def _rebuild_shard_body(
         (i, b) for i, b in enumerate(stripe.blocks) if i != shard
     ]
     nw = tuple(int(b.sealed.n_valid_u32) for _, b in survivors)
-    sealed = jnp.stack(
-        [
-            jnp.pad(b.sealed.body, (0, pad_to - int(b.sealed.body.shape[0])))
-            .reshape(R, 128)
-            for _, b in survivors
-        ]
-    )
+    sealed = stack_rows([b.sealed.body for _, b in survivors], R)
     packed = SealedStripe(sealed, None, None, nw, nw)
     S = len(survivors)
     zero_k = jnp.zeros((S, 8), jnp.uint32)

@@ -12,12 +12,16 @@ matching, VPU), ``quantize`` (blockwise int8, VPU), ``entropy``
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["use_interpret", "as_payload_list"]
+__all__ = [
+    "use_interpret", "as_payload_list", "stack_rows", "host_prefixes",
+    "le_words",
+]
 
 
 def use_interpret(interpret: Optional[bool] = None) -> bool:
@@ -37,12 +41,46 @@ def as_payload_list(payloads) -> List[jax.Array]:
     to a list of flat int8 arrays — shared by the seal and entropy ops."""
     if isinstance(payloads, (list, tuple)):
         # already-normalized payloads (the hot path) pass through without
-        # paying a per-shard reshape/astype dispatch
+        # paying a per-shard reshape/astype dispatch; host payloads stay on
+        # the host until they are staged into a launch
         return [
             p
-            if isinstance(p, jax.Array) and p.dtype == jnp.int8 and p.ndim == 1
+            if isinstance(p, (jax.Array, np.ndarray))
+            and p.dtype == jnp.int8 and p.ndim == 1
             else jnp.asarray(p).reshape(-1).astype(jnp.int8)
             for p in payloads
         ]
     arr = jnp.asarray(payloads)
     return [arr[s].reshape(-1).astype(jnp.int8) for s in range(arr.shape[0])]
+
+
+def stack_rows(flats: Sequence, rows: int, width: int = 128,
+               dtype=np.uint32) -> jax.Array:
+    """Ragged flat arrays -> one zero-padded (S, rows, width) device array.
+
+    Staged on the host with one transfer: a device-side pad per ragged
+    length would compile a program per distinct size."""
+    host = np.zeros((len(flats), rows * width), dtype)
+    for s, f in enumerate(flats):
+        v = np.asarray(f).reshape(-1).view(dtype)
+        host[s, : v.shape[0]] = v
+    return jax.device_put(host.reshape(len(flats), rows, width))
+
+
+def host_prefixes(arr, lengths: Sequence[int]) -> List[np.ndarray]:
+    """Row s of a device array, flattened and cut to ``lengths[s]``, as host
+    arrays from ONE fetch (no per-length device program)."""
+    host = np.asarray(arr).reshape(len(lengths), -1)
+    return [host[s, :n] for s, n in enumerate(lengths)]
+
+
+def le_words(x, k: int) -> jax.Array:
+    """(B, m) little-endian parts of 32/k bits each -> (B, ceil(m/k)) u32
+    words (zero-filled).  Strided lane slices: a reshape to (B, m/k, k)
+    would give XLA a k-wide minor axis, which it lays out lane-padded and
+    compiles very slowly for a TPU."""
+    x = jnp.pad(x.astype(jnp.uint32), ((0, 0), (0, -x.shape[1] % k)))
+    out = x[:, 0::k]
+    for i in range(1, k):
+        out = out | (x[:, i::k] << jnp.uint32(32 // k * i))
+    return out

@@ -4,7 +4,7 @@ stream packing, accounting.
 ``encode_payloads`` / ``decode_payloads`` accept ragged per-shard payloads,
 pad them to the kernel's (T, 128) lane grid (T pow2-bucketed like
 ``seal_ops.bucket_rows_for`` so jit traces stay bounded for mixed GOP
-sizes), dispatch either the fused Pallas coder (one launch per stripe) or
+sizes), dispatch either the Pallas coder (histogram + coding kernels) or
 the staged jnp oracle (``use_pallas=False``), and pack the result into a
 self-contained compressed byte stream per shard:
 
@@ -28,18 +28,13 @@ v1 — its offsets are only *required* for v0's re-gather), so a version
 bump never changes ``n_comp``: the compression ratio is identical by
 construction.
 
-Compaction of the dense emission buffer is a two-level rank-select *gather*
-(scatter-free: XLA scatters serialize on TPU and CPU alike): the k-th
-output word's row comes from a scatter-max + running-max over the 512-odd
-row offsets, and its lane from a branchless binary search over the in-row
-prefix sums (5 u8 gather rounds to an aligned 4-lane block, one u32 gather
-for the block's boundary prefixes).  The search width is *tiered*: the
-pack's static capacity is the raw-skip worst case, and a ``lax.cond`` drops
-to half width whenever the batch's measured emission counts fit — which is
-what lets the encode pipeline run with no mid-stream host sync.  ``core_fn`` overrides the coder launch itself; the
-sharded path (``repro.distributed.archival``) passes a shard_map'd wrapper
-with the same signature, exactly like ``seal_fn``/``unseal_fn`` in the
-seal pipeline.
+Compaction of the emitted words into the row-major stream happens inside
+the encode kernel (``rans.rans_encode_pallas``); this module serializes the
+header and the word area as little-endian u32 words (``stream_words``, the
+stream's bytes four to a word).  ``core_fn`` overrides the coder launch
+itself; the sharded path (``repro.distributed.archival``) passes a
+shard_map'd wrapper with the same signature, exactly like
+``seal_fn``/``unseal_fn`` in the seal pipeline.
 """
 
 from __future__ import annotations
@@ -51,22 +46,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import as_payload_list, use_interpret
+from repro.kernels import (
+    as_payload_list,
+    host_prefixes,
+    le_words,
+    stack_rows,
+    use_interpret,
+)
 from repro.kernels.entropy import ref as _ref
 from repro.kernels.entropy.rans import (
     N_LANES,
     STREAM_VERSION,
     T_TILE,
     rans_decode_pallas,
-    rans_decode_pallas_v0,
     rans_encode_pallas,
+    stream_word_cap,
 )
 
 __all__ = [
     "HEADER_BYTES",
     "MAX_ROWS",
     "rows_for",
-    "cap_for",
     "stream_word_cap",
     "encode_payloads",
     "decode_payloads",
@@ -94,60 +94,25 @@ def rows_for(n_bytes: int) -> int:
     return T_TILE * (1 << (tiles - 1).bit_length())
 
 
-def cap_for(n_words: int) -> int:
-    """Pow2 word capacity bucket (>= 1) for a known emission count.
-
-    Legacy sizing helper: the encode path used to sync the emission counts
-    to the host mid-pipeline to jit-specialize the pack on this bucket; it
-    now packs at the static worst case (:func:`stream_word_cap`) with the
-    tiered rank-select, so no device->host round-trip splits the encode.
-    Kept for callers sizing scratch buffers off a known word count.
-    """
-    return 1 << max(0, int(n_words - 1).bit_length())
-
-
-def stream_word_cap(T: int) -> int:
-    """Worst-case u16 stream words worth packing for a T-row shard (any
-    shard emitting more compresses to >= its raw size and is stored raw,
-    so capping the pack here discards only streams the raw-skip select
-    would discard anyway — the packed words are position-exact for ANY
-    cap, see :func:`_pack_rank_impl`)."""
-    return max(1, (T * N_LANES - HEADER_BYTES) // 2)
-
-
-def _u16_to_u8(w: jax.Array) -> jax.Array:
-    """(..., n) uint16 -> (..., 2n) uint8, little-endian."""
-    lo = (w & jnp.uint16(0xFF)).astype(jnp.uint8)
-    hi = (w >> jnp.uint16(8)).astype(jnp.uint8)
-    return jnp.stack([lo, hi], axis=-1).reshape(*w.shape[:-1], -1)
-
-
-def _u32_to_u8(w: jax.Array) -> jax.Array:
-    """(..., n) uint32 -> (..., 4n) uint8, little-endian."""
-    parts = [
-        ((w >> jnp.uint32(8 * k)) & jnp.uint32(0xFF)).astype(jnp.uint8)
-        for k in range(4)
-    ]
-    return jnp.stack(parts, axis=-1).reshape(*w.shape[:-1], -1)
-
-
 @functools.partial(
     jax.jit, static_argnames=("use_pallas", "interpret", "division")
 )
 def _encode_core(codes, n_valid, *, use_pallas: bool, interpret: bool,
                  division: Optional[str] = None):
-    if division is None:
-        # interpret/CPU: the shifted-reciprocal mulhi path beats LLVM's
-        # udiv ~18% — x86 has no vector u32 divide, so udiv scalarizes
-        # while mulhi stays SIMD; real TPU: Mosaic has no integer divide,
-        # the repaired-f32 reciprocal is the fast exact replacement (all
-        # three strategies are bit-identical)
-        division = "reciprocal" if interpret else "rcp32"
+    """The coder launch -> (words, n_words, lane_lens, freq, states), the
+    compacted stream words of ``rans.rans_encode_pallas``.  ``division``
+    picks the jnp oracle's per-symbol division strategy
+    (``use_pallas=False``); the kernel always runs the repaired f32
+    reciprocal — all strategies give identical bits."""
     if use_pallas:
-        return rans_encode_pallas(
-            codes, n_valid, division=division, interpret=interpret
-        )
-    return _ref.rans_encode_ref(codes, n_valid, division=division)
+        return rans_encode_pallas(codes, n_valid, interpret=interpret)
+    words, mask, freq, states = _ref.rans_encode_ref(
+        codes, n_valid, division=division or "divide"
+    )
+    comp, n_words, lane_lens = _ref.compact_ref(
+        words, mask, stream_word_cap(codes.shape[1])
+    )
+    return comp, n_words, lane_lens, freq, states
 
 
 @functools.partial(
@@ -156,10 +121,7 @@ def _encode_core(codes, n_valid, *, use_pallas: bool, interpret: bool,
 def _decode_core(words, freq, states, n_valid, *, version: int, rows: int,
                  use_pallas: bool, interpret: bool):
     if version == 0:
-        if use_pallas:
-            return rans_decode_pallas_v0(
-                words, freq, states, n_valid, interpret=interpret
-            )
+        # PR-4 lane-major streams decode through the jnp oracle only
         return _ref.rans_decode_ref_v0(words, freq, states, n_valid)
     if use_pallas:
         return rans_decode_pallas(
@@ -168,165 +130,24 @@ def _decode_core(words, freq, states, n_valid, *, version: int, rows: int,
     return _ref.rans_decode_ref(words, freq, states, n_valid, rows=rows)
 
 
-def _pack_rank_impl(mask, *, cap: int, tiered: bool = False):
-    """Stage 1 of the rank-select pack: per-output-slot source positions.
-
-    For each output slot k the source row is recovered from a scatter-max
-    of row ids at their stream offsets followed by a running max (the same
-    cumulative-bucket fill the decoder uses for its slot table), and the
-    source lane by a branchless bit-step lower bound over the u8 in-row
-    prefix sums — every wide op is a gather, which vectorizes where a
-    word-per-word scatter would serialize.  (A one-scatter inverse — write
-    each word at ``row_off + rank`` — measured ~1.6x SLOWER than these
-    gathers at the fused kernel's batch size: XLA:CPU serializes the 2M
-    element stores.)
-
-    ``tiered=True`` (both the fused kernel and the host pack, whose
-    ``cap`` is the static worst-case ``stream_word_cap``, ~2.5x a typical
-    emission count) bounds the per-slot work by the *measured* batch: when
-    no shard emits more than cap/2 words a ``lax.cond`` runs the
-    rank-select at half width and zero-pads — slots past every shard's
-    ``n_words`` are zeroed by the word pass anyway, so the outputs are
-    bit-identical.  Packing at the static worst case is what lets the
-    encode pipeline run sync-free: no device->host emission-count round
-    trip is needed to size the pack buffer.
-    """
-    S, T, L = mask.shape
-    lm = mask != 0                                           # (S, T, L)
-    # u8 in-row inclusive prefix (row counts <= 128 fit): 4x less traffic
-    # for the rank-select gathers below, and the per-row totals fall out
-    # of its last lane for free.  (A log-depth shift-add spelling of this
-    # prefix measured 3x faster in isolation but SLOWER in situ — its 7
-    # materialized intermediates break the fusion with the rank gathers
-    # below — so the associative-scan form stands.)
-    icsum3 = jnp.cumsum(lm.astype(jnp.uint8), axis=2, dtype=jnp.uint8)
-    cnt = icsum3[:, :, L - 1].astype(jnp.int32)              # (S, T)
-    row_off = jnp.cumsum(cnt, axis=1) - cnt                  # exclusive
-    n_words = cnt.sum(axis=1)                                # (S,)
-    # per-lane emission counts, log-depth halving tree: XLA:CPU lowers the
-    # strided axis-1 reduce of the (S, T, L) mask to column loads that
-    # don't vectorize (2.6x slower than this tree at the fused batch size)
-    x = lm.astype(jnp.int32)
-    while x.shape[1] > 1:
-        h = x.shape[1] // 2
-        x = x[:, :h] + x[:, h:]
-    lane_lens = x[:, 0]                                      # (S, L)
-    icsum = icsum3.reshape(S, T * L)
-
-    # u32 view of the prefix grid: the final binary-search level reads 4
-    # adjacent u8 prefixes as one aligned word (bitcast semantics are
-    # HLO-level deterministic: element 0 -> least significant byte)
-    icsum4 = jax.lax.bitcast_convert_type(
-        icsum3.reshape(S, T * L // 4, 4), jnp.uint32
-    )
-
-    def src_for(c: int):
-        # source row of output k (k < c): last row whose offset is <= k.
-        # Row ids fit u16 at any T below the MAX_ROWS edge, so the
-        # scatter-max + running max scan move half the bytes of the i32
-        # spelling (dtype picked on the static T)
-        idt = jnp.uint16 if T <= 0xFFFF else jnp.int32
-        rows_iota = jnp.broadcast_to(jnp.arange(T, dtype=idt), (S, T))
-        marks = (
-            jnp.zeros((S, c), idt)
-            .at[jnp.arange(S)[:, None], row_off]
-            .max(rows_iota, mode="drop")
+def stream_words(words, lane_lens, freq, states):
+    """Header + word area of B v1 streams as little-endian u32 words ->
+    (B, 384 + ceil(cap / 2)) uint32: the stream's bytes, four per word
+    (the 1536-byte header is 384 whole words).  ``words`` (B, cap) uint16
+    is zero past each shard's emission count."""
+    with jax.named_scope("rans_serialize"):
+        return jnp.concatenate(
+            [le_words(freq, 2), lane_lens.astype(jnp.uint32),
+             states.astype(jnp.uint32), le_words(words, 2)],
+            axis=1,
         )
-        row_id = jax.lax.cummax(marks, axis=1).astype(jnp.int32)  # (S, c)
-        k = jnp.arange(c, dtype=jnp.int32)[None, :]
-        j1 = (
-            k - jnp.take_along_axis(row_off, row_id, axis=1) + 1
-        ).astype(jnp.uint8)                                  # in-row rank + 1
-
-        # source lane: smallest l with icsum[row, l] >= j + 1.  Branchless
-        # bit-step lower bound, wide ops only: 5 u8 gather rounds narrow to
-        # an aligned 4-lane block, then ONE u32 gather reads that block's
-        # remaining 3 boundary prefixes and 2 compare-adds finish the rank
-        # — 6 gathers total where the naive 7-round search pays 7
-        base = row_id * L
-        lane = jnp.zeros((S, c), jnp.int32)
-        for b in (64, 32, 16, 8, 4):
-            t = lane | b
-            v = jnp.take_along_axis(icsum, base + t - 1, axis=1)
-            lane = jnp.where(v < j1, t, lane)
-        quad = jnp.take_along_axis(
-            icsum4, (base >> 2) + (lane >> 2), axis=1
-        )
-        j32 = j1.astype(jnp.uint32)
-        lane += (
-            ((quad & jnp.uint32(0xFF)) < j32).astype(jnp.int32)
-            + (((quad >> jnp.uint32(8)) & jnp.uint32(0xFF)) < j32).astype(
-                jnp.int32
-            )
-            + (((quad >> jnp.uint32(16)) & jnp.uint32(0xFF)) < j32).astype(
-                jnp.int32
-            )
-        )
-        return base + lane
-
-    half = cap // 2
-    if tiered and half >= 1:
-        src = jax.lax.cond(
-            jnp.max(n_words) <= half,
-            lambda: jnp.pad(src_for(half), ((0, 0), (0, cap - half))),
-            lambda: src_for(cap),
-        )
-    else:
-        src = src_for(cap)
-    return src, n_words, lane_lens
 
 
-
-
-def _pack_bytes_impl(words, src, n_words, lane_lens, freq, states):
-    """Stage 2: gather the words into stream order and serialize header +
-    word area to bytes."""
-    S, T, L = words.shape
-    cap = src.shape[1]
-    w = jnp.take_along_axis(words.reshape(S, T * L), src, axis=1)
-    k = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    comp_words = jnp.where(k < n_words[:, None], w, 0)
-    header = jnp.concatenate(
-        [
-            _u16_to_u8(freq.astype(jnp.uint16)),
-            _u32_to_u8(lane_lens.astype(jnp.uint32)),
-            _u32_to_u8(states),
-        ],
-        axis=1,
-    )
-    return jnp.concatenate([header, _u16_to_u8(comp_words)], axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=("cap",))
-def _pack_streams(words, mask, freq, states, *, cap: int):
-    """One-dispatch host pack: tiered rank-select + byte serialize.
-
-    Returns (packed int8 streams (S, HEADER + 2*cap) — int8 so the exact-
-    length shard slices need no per-shard cast — and the (S,) emission
-    counts).  The plain ``_pack_rank_impl``/``_pack_bytes_impl`` bodies are
-    also traced *inside* the one-launch entropy+seal kernel
-    (``repro.kernels.fused``), where an extra jit boundary would be a bug;
-    with the tiered rank-select the single-jit spelling measures identical
-    to split dispatches, so the host path takes the fewer-roundtrips form.
-    """
-    src, n_words, lane_lens = _pack_rank_impl(mask, cap=cap, tiered=True)
-    comp = _pack_bytes_impl(words, src, n_words, lane_lens, freq, states)
-    return comp.astype(jnp.int8), n_words
-
-
-@functools.partial(jax.jit, static_argnames=("rows",))
-def _stage_codes(flats, rows: int):
-    """Pad ragged shard payloads to the (rows, 128) lane grid in ONE traced
-    dispatch (shape-keyed cache: one trace per distinct payload-length mix,
-    the same bound eager per-shard pads paid in per-op dispatches)."""
-    return jnp.stack(
-        [
-            jnp.pad(f, (0, rows * N_LANES - f.shape[0])).reshape(
-                rows, N_LANES
-            )
-            for f in flats
-        ]
-    )
+@jax.jit
+def _pack_streams(words, n_words, lane_lens, freq, states):
+    """Host-path serialize: (the streams as u32 words — the host views
+    them as bytes — and the (S,) emission counts)."""
+    return stream_words(words, lane_lens, freq, states), n_words
 
 
 def _parse_header(comp):
@@ -418,24 +239,18 @@ def encode_payloads(
             f"payload of {max(n_raw)} bytes needs {T} lane rows (max "
             f"{MAX_ROWS}); split it across more stripe shards"
         )
-    codes = _stage_codes(flats, rows=T)
+    codes = stack_rows(flats, T, N_LANES, np.int8)
     n_valid = jnp.asarray(n_raw, jnp.int32).reshape(-1, 1)
     if core_fn is None:
         core_fn = functools.partial(
             _encode_core, use_pallas=use_pallas,
             interpret=use_interpret(interpret), division=division,
         )
-    words, mask, freq, states = core_fn(codes, n_valid)
-    # pack at the static raw-skip worst case (no mid-pipeline host sync to
-    # size the buffer — the tiered rank-select recovers the tight-bucket
-    # cost whenever the batch's true counts allow)
-    comp_pad, n_words_dev = _pack_streams(
-        words, mask, freq, states, cap=stream_word_cap(T)
-    )
+    comp_pad, n_words_dev = _pack_streams(*core_fn(codes, n_valid))
     # ONE blocking device->host fetch covers the stream bytes and the
     # emission counts the manifest needs; slicing the host buffer is then
     # free, where per-shard eager device slices each paid a dispatch
-    buf = np.asarray(comp_pad)
+    buf = np.asarray(comp_pad).view(np.int8)
     n_words = [int(n) for n in np.asarray(n_words_dev)]
     n_comp = [HEADER_BYTES + 2 * nw for nw in n_words]
     comps, metas = [], []
@@ -465,7 +280,7 @@ def decode_payloads(
     use_pallas: bool = True,
     interpret: Optional[bool] = None,
     core_fn=None,
-) -> List[jax.Array]:
+) -> List[np.ndarray]:
     """Decode twin: compressed streams + metas -> exact original payloads.
 
     Dispatches on the *recorded* stream ``version`` (absent = 0, the PR-4
@@ -473,7 +288,8 @@ def decode_payloads(
     Shards the encoder flagged ``raw`` (adaptive raw-skip: compressed would
     have been >= raw) pass through untouched; only the genuinely coded
     shards enter the kernel launch, so a stripe that mixes both still runs
-    one launch.  Works identically under the sharded ``core_fn``.
+    one launch.  Works identically under the sharded ``core_fn``.  Returns
+    host int8 arrays.
     """
     if len(comps) != len(metas):
         raise ValueError(f"{len(comps)} streams vs {len(metas)} metas")
@@ -482,8 +298,10 @@ def decode_payloads(
     T = int(metas[0]["rows"])
     if any(int(m["rows"]) != T for m in metas):
         raise ValueError("all shards of a stripe share one padded row count")
-    flats = [jnp.asarray(c).reshape(-1).astype(jnp.uint8) for c in comps]
-    out: List[Optional[jax.Array]] = [None] * len(flats)
+    # ragged streams are staged and cut on the host: a device pad or slice
+    # per ragged length would compile a program per GOP size
+    flats = [np.asarray(c).reshape(-1).astype(np.uint8) for c in comps]
+    out: List[Optional[np.ndarray]] = [None] * len(flats)
     coded: List[int] = []
     for i, (f, m) in enumerate(zip(flats, metas)):
         if int(f.shape[0]) != int(m["n_comp"]):
@@ -496,7 +314,7 @@ def decode_payloads(
                     f"raw-skip shard must store n_raw bytes, manifest says "
                     f"{m['n_comp']} vs {m['n_raw']}"
                 )
-            out[i] = f.astype(jnp.int8)
+            out[i] = f.view(np.int8)
             continue
         if int(f.shape[0]) < HEADER_BYTES:
             raise ValueError("compressed stream shorter than its header")
@@ -508,11 +326,17 @@ def decode_payloads(
                 f"stripe mixes stream versions {sorted(versions)}"
             )
         version = versions.pop()
-        sub = [flats[i] for i in coded]
-        # common padded width, stream area even and >= one word (tails unread)
-        C = max(max(int(f.shape[0]) for f in sub), HEADER_BYTES + 2)
+        # common padded width: the bucket's stream capacity (one program
+        # per row bucket), stream area even and >= one word (tails unread)
+        C = max(
+            HEADER_BYTES + 2 * stream_word_cap(T),
+            max(int(flats[i].shape[0]) for i in coded),
+        )
         C += (C - HEADER_BYTES) % 2
-        comp = jnp.stack([jnp.pad(f, (0, C - f.shape[0])) for f in sub])
+        comp = np.zeros((len(coded), C), np.uint8)
+        for j, i in enumerate(coded):
+            comp[j, : flats[i].shape[0]] = flats[i]
+        comp = jnp.asarray(comp)
         if version == 0:
             words, freq, states = _parse_streams_v0(comp, rows=T)
         else:
@@ -526,8 +350,9 @@ def decode_payloads(
                 interpret=use_interpret(interpret),
             )
         codes = core_fn(words, freq, states, n_valid, version=version, rows=T)
+        got = host_prefixes(codes, [int(metas[i]["n_raw"]) for i in coded])
         for j, i in enumerate(coded):
-            out[i] = codes[j].reshape(-1)[: int(metas[i]["n_raw"])]
+            out[i] = got[j]
     return out
 
 

@@ -1,117 +1,65 @@
-"""Pallas TPU kernel: interleaved-rANS byte coder for the archival datapath.
+"""Pallas TPU kernels: interleaved-rANS byte coder for the archival datapath.
 
-One launch codes all S shards of a stripe: the stripe is the kernel block,
-and the shards ride the batch axis of every vector op, so one loop step
-feeds S x 128 lanes to the vector unit instead of idling per shard.  A
-shard's flat int8 payload is laid out as (T, 128) rows whose 128 columns
+A shard's flat int8 payload is laid out as (T, 128) rows whose 128 columns
 are 128 *independent* rANS lanes (lane l owns bytes l, 128+l, 256+l, ...),
 the interleaved layout from Giesen's SIMD rANS with the lane axis mapped
-onto the TPU lane dimension.
+onto the TPU lane dimension.  32-bit states with 16-bit renormalization
+mean a lane emits (or, decoding, consumes) at most one 16-bit word per row,
+which is what makes the coder branchlessly vectorizable.
 
-The coding loop is a *two-phase* encode with no ``fori_loop`` anywhere:
+The encode of a batch of B shards is three device programs:
 
-  phase 1 computes the whole row/lane schedule as batched tensor ops —
-  the (T, S, 128) validity of every position against ``n_valid`` in one
-  iota compare, and one select that swaps every invalid position's
-  pregathered symbol table entry for the *identity sentinel*
-  (f = PROB_SCALE, cum = 0: the state update collapses to
-  x' = x + q*(M - f) + c = x and the renorm test (x >> 20) >= PROB_SCALE
-  cannot fire for any 32-bit state, so the step is an exact no-op and the
-  lane freezes).  That removes every per-lane mask, compare and select
-  from the sequential region: boundary rows, fully-padded rows and
-  ``n_valid = 0`` dummy shards all ride the same unmasked body;
+  1. ``rans_histogram`` (Pallas): per-shard byte histogram on the MXU.  The
+     byte splits into hi/lo nibbles; for each 8-row group the one-hot
+     matrices A[(h, r), p] = [hi(r, p) = h] and B[(l, r), p] = [lo(r, p) = l]
+     (128 x 128, bf16 0/1) multiply as A @ B^T, and the diagonal r = r'
+     blocks of the product are the group's (16 x 16) joint nibble counts.
+     Every partial sum is an integer below 2^24, so f32 accumulation is
+     exact.  Row tiles ride an ``"arbitrary"`` grid axis.
+  2. ``rans_tables`` (XLA): :func:`build_freq_table` and
+     :func:`build_enc_tables` on the (B, 256) counts — table-sized work with
+     cumulative sums and an argmax that Mosaic does not lower.
+  3. ``rans_encode`` (Pallas): the coding loop.  rANS encodes backwards, so
+     the grid walks the row tiles in reverse on an ``"arbitrary"`` axis with
+     the (B, 128) lane states in VMEM scratch; inside a tile a
+     ``fori_loop`` walks the rows in reverse.  Codes arrive row-major as
+     (T, B, 128) so one row of every shard is one (B, 128) vector: shards
+     ride the sublanes.  Each row looks its symbols' table entries up with
+     two in-register lane gathers (the 256-entry tables are two 128-lane
+     halves), swaps padding positions for the identity sentinel (see
+     ``_ENC_SENTINEL``), and steps every lane once.  The row's emitted
+     words are compacted on the spot: an exclusive in-row prefix sum (one
+     0/1 matmul against an upper-triangular matrix) gives each emitting
+     lane its slot, a one-hot placement matmul moves the words (as bytes,
+     exact in bf16) to their lanes, and they are merged into a per-shard
+     VMEM window of the stream.  rANS runs backwards, so the stream is
+     written back to front; the window is refilled from and flushed to
+     HBM by DMA once per row tile, and XLA slices each shard's stream
+     from its final write position.  The output is the v1 word area
+     itself — no dense per-position buffer leaves the kernel.
 
-  phase 2 is a minimal-carry ``lax.scan`` over the rows (reverse order —
-  rANS encodes backwards so decode streams forwards) whose carry is ONLY
-  the (S, 128) lane states; the per-row emitted words and emission masks
-  leave through the scan's stacked outputs instead of a dense carry
-  buffer threaded through a ``fori_loop``, which is what let XLA:CPU
-  vectorize the row I/O instead of serializing a (T, S, 128)
-  dynamic-update chain.  The per-lane word counts and exclusive stream
-  offsets then fall out of the emission mask as batched prefix sums, and
-  ``ops.py`` writes every output word with one rank-select gather pass
-  against those precomputed offsets.
+The per-symbol division x // freq runs inside the kernel as the
+error-repaired f32 reciprocal (``division="rcp32"`` of :func:`_enc_step`):
+Mosaic has no integer division.  The renorm invariant bounds the quotient
+by 2^20, so any faithful rounding is within +-0.2 of the true quotient and
+the +-1 integer repair makes the result exact — bit-identical to the
+hardware udiv and the Granlund-Montgomery mulhi strategies, which the jnp
+oracle (``ref.py``) still runs.
 
-The scan *step width* stays a static knob (``rows_per_step``): each scan
-trip advances that many rows, 1 under interpret (many tiny ops schedule
-cheaper than few fat fused bodies on CPU) and an (N_GROUPS=8, 128)
-sublane-by-lane vreg tile on TPU.  The schedule cannot change a single
-output bit — only which ops compute them — and the suite asserts both
-schedules bit-identical.  (Widening the *state* interleave instead —
-G x 128 independent streams — was measured and rejected: every extra rANS
-stream wastes >= 16 bits of initial-state flush for zero entropy gain,
-~2.7 KiB per 64 KiB shard, about a 10% compression-ratio loss.)
+Decoding (``rans_decode``, Pallas) mirrors it forward: per row, the symbol
+is found by an 8-step branchless binary search over the shard's inclusive
+cumulative frequencies (lane gathers again), the lanes that renormalize
+take the next words of the row-major stream in lane order (exclusive
+in-row prefix sum as one 0/1 matmul against an upper-triangular matrix),
+and the words come from a per-shard VMEM window of the stream that a DMA
+refills at the start of each row tile: consumption is at most 128 words
+per row, so a tile of R rows reads at most R + 2 stream rows past its
+start.  The read pointers are scalars (one per shard).
 
-Per shard the kernel runs three fused stages without leaving VMEM:
-
-  1. histogram over all T*128 bytes, by one of two exact, bit-identical
-     strategies (the ``histogram`` knob, defaulted per backend like
-     ``division``): ``"dot"`` — the one-hot *matmul*: the byte splits
-     into hi/lo nibbles and hist.reshape(16, 16) = onehot(hi)^T @
-     onehot(lo), an (N, 16) x (N, 16) f32 contraction — exact because
-     every partial sum is an integer <= T*128 <= 2^24, below the f32
-     mantissa (the TPU default: the MXU eats it); or ``"swar"`` — pack
-     bytes 4-per-u32, XOR against each candidate symbol's splatted
-     pattern, SWAR zero-byte detect, ``population_count``, and an
-     explicit halving-tree add reduction (the interpret/CPU default:
-     ~3x the one-hot GEMM, whose 16-wide M/N tiles leave the CPU GEMM at
-     a quarter of peak, and the *tree* matters — XLA:CPU's own reduce
-     lowering over the word axis was measured 14x slower than the same
-     adds spelled as a log-depth slice chain).  Neither path scatters
-     (``.at[...].add`` serializes on TPU and CPU alike;
-     ``test_kernel_hygiene.py`` bans it from kernel sources);
-  2. static table build: :func:`build_freq_table` (integer-exact
-     normalization to ``M = 2**PROB_BITS``, every present symbol >= 1)
-     plus :func:`build_enc_tables`, which precomputes per-symbol
-     reciprocals so the hot loop never divides: the Granlund-Montgomery
-     (mprime, shift) fixed-point pair, and an f32 reciprocal for the
-     error-repaired fast path.  The frequency table ships in the stream
-     header; the reciprocals are *derived* state — decode is
-     multiplication-only and provably never reads them, so shipping them
-     would inflate every stream by 1.25 KiB for nothing;
-  3. the two-phase coding loop described above, emitting at most one
-     16-bit word per lane per row (32-bit states, 16-bit renormalization:
-     state in [2^16, 2^32) means renorm fires at most once per symbol,
-     which is what makes the loop branchlessly vectorizable).  Symbol
-     tables are pregathered per position and sentinel-masked before the
-     scan, so the sequential region reads only aligned row slices and
-     carries only the lane states — no gathers, no masks, no dense
-     output buffer on the hot path.
-
-The per-symbol division x // freq runs as one of three exact,
-bit-identical strategies (see :func:`_enc_step`): the all-integer
-Granlund-Montgomery mulhi (interpret default — x86 has no vector u32
-divide, so udiv scalarizes while mulhi stays SIMD), the error-repaired
-f32 reciprocal multiply (TPU default — Mosaic has no integer division,
-which is what kept the PR-3 coder off real hardware), or the hardware
-udiv.
-The f32 path is immune to the x/c -> x*(1/c) jit canonicalization that
-breaks naive float kernels: the renorm invariant bounds the quotient by
-2^20, so any faithful rounding stays within +-0.2 of the true quotient
-and the integer repair makes the result exact.  Everything else in the
-coder is u32/i32 (and the histogram's f32 counts are
-exact-by-construction), so kernel-vs-reference bit-exactness survives
-every backend.
-
-Stream format (``STREAM_VERSION = 1``): the header layout is unchanged
-from version 0 — freq u16[256] | lane_lens u32[128] | states u32[128] —
-but the word area is packed in *row-major decoder-read order* (the global
-order a forward decode consumes words: row by row, lanes in order within
-a row) instead of version 0's per-lane-contiguous runs.  Row-major
-packing is what the vectorized decoder wants: each step takes the next
-popcount(need) words off the stream front with an in-register prefix
-sum, so no per-lane offset table is parsed and no ``searchsorted`` exists
-anywhere — the slot->symbol table is a direct cumulative-bucket fill
-(:func:`slot_to_symbol`: scatter-max the symbol ids at their cumulative
-start slots, then a running max).  The version bump never changes
-``n_comp`` (same header bytes, same word count), so the compression ratio
-is identical by construction; version 0 streams still decode through the
-lane-major twin (``rans_decode_pallas_v0``), and the stream version rides
-in the archive manifest next to the codec name.
-
-The encoder does NOT compact its output: it writes a dense (T, 128) word
-buffer plus an emission mask, and ``ops.py`` runs the (shared,
-order-free) rank-select compaction into the final byte stream.
+Stream format (``STREAM_VERSION = 1``): header freq u16[256] |
+lane_lens u32[128] | states u32[128], then the words in *row-major
+decoder-read order* (row by row, lanes in order within a row).  Version 0
+streams (PR-4 lane-major words) decode through the jnp oracle only.
 """
 
 from __future__ import annotations
@@ -121,10 +69,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "N_LANES",
-    "N_GROUPS",
     "PROB_BITS",
     "PROB_SCALE",
     "RANS_L",
@@ -134,39 +82,44 @@ __all__ = [
     "build_enc_tables",
     "build_dec_table",
     "slot_to_symbol",
-    "rans_encode_body",
+    "byte_histogram",
+    "stream_word_cap",
     "rans_encode_pallas",
     "rans_decode_pallas",
-    "rans_decode_pallas_v0",
 ]
 
 N_LANES = 128                 # interleaved rANS lanes == TPU lane width
-N_GROUPS = 8                  # lane-group rows per tile == TPU sublane width
 PROB_BITS = 12                # frequency table quantization: sum(freq) = 4096
 PROB_SCALE = 1 << PROB_BITS
 RANS_L = 1 << 16              # state lower bound; 16-bit renormalization
-T_TILE = 8                    # sublane-aligned row granularity (== N_GROUPS)
+T_TILE = 8                    # sublane-aligned row granularity
 STREAM_VERSION = 1            # row-major word order; 0 = PR-4 lane-major
 
 _SYM_MASK = 0x1FFF            # 13 bits: freq and cum both reach 4096
+_SUBLANES = 8                 # shards are padded to whole sublane groups
+_HIST_TILE = 512              # rows per histogram grid step
+_DEC_TILE = 128               # rows per decode grid step (one stream DMA)
+_TILE_ELEMS = 1 << 18         # encode block budget: rows * shards * 128
 
 
 def build_freq_table(counts: jax.Array) -> jax.Array:
     """(256,) int32 byte counts -> (256,) int32 freqs summing to PROB_SCALE.
+
+    Precondition: ``counts.sum() < 2^31`` — the total is summed in int32.
+    A shard holds at most ``MAX_ROWS * 128 = 2^24`` bytes, so every real
+    histogram meets it.
 
     Integer-exact and overflow-safe in int32: counts are right-shifted until
     their total is < 2^19 (so count*budget < 2^31), every present symbol is
     reserved one slot up front, the remaining budget is floor-allocated
     proportionally, and the rounding remainder goes to the most frequent
     symbol.  Present symbols always get freq >= 1; the sum is exactly
-    PROB_SCALE.  Shared verbatim by the Pallas kernel and the jnp reference
-    (same role as ``chacha_rounds_planes`` in the seal kernel).
+    PROB_SCALE.  Shared verbatim by the coder's XLA table stage and the jnp
+    reference.
     """
     present = (counts > 0).astype(jnp.int32)
     total = counts.sum()
     # shift = #{k : total >= 2^(19+k)}  -- smallest shift with total>>shift < 2^19
-    # (iota, not arange: materialized constants cannot be captured by a
-    # pallas kernel body, computed iotas can)
     thresholds = 19 + jax.lax.broadcasted_iota(jnp.int32, (12,), 0)
     shift = (total >= (1 << thresholds)).sum()
     c2 = jnp.maximum(counts >> shift, present)
@@ -264,24 +217,14 @@ def _mulhi_u32(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def _histogram(vals: jax.Array, n_valid) -> jax.Array:
-    """Exact byte histogram of a zero-padded (T, 128) shard -> (256,) int32.
+    """Exact byte histogram of a zero-padded (T, 128) shard -> (256,) int32:
+    the jnp oracle of :func:`byte_histogram`.
 
     One-hot matmul form: hist.reshape(16, 16) = onehot(hi)^T @ onehot(lo),
-    an (N, 16) x (N, 16) f32 contraction over N.  Exact by IEEE
-    arithmetic, not by luck: every product is 0 or 1 and every partial
-    sum is an integer bounded by N = T*128 <= MAX_ROWS*128 = 2^24, and
-    integers up to 2^24 are exactly representable in f32, so any
-    accumulation order yields the true count.  f32 operands matter on
-    the CPU interpret backend, where the previous int8-accumulate-int32
-    contraction missed the optimized GEMM and its naive fallback loop
-    cost more than the entire coding loop (the MXU is indifferent — it
-    eats f32 natively).  The one-hots are identity-row gathers (a serial
-    gather materializes the operands cheaper than broadcast
-    compare+convert, and the iota-equality identity is computed because
-    pallas kernels cannot capture materialized constants).  Padding
-    positions past ``n_valid`` are *zero bytes* by the ``ops.py``
-    contract, so their whole contribution lands in bin 0 and is
-    subtracted back out — exact, and cheaper than masking the one-hot.
+    an (N, 16) x (N, 16) f32 contraction over N — exact, because every
+    partial sum is an integer <= N = T*128 <= 2^24.  Padding positions past
+    ``n_valid`` are *zero bytes* by the ``ops.py`` contract, so their whole
+    contribution lands in bin 0 and is subtracted back out.
     """
     n = vals.shape[0] * vals.shape[1]
     v = vals.reshape(n)
@@ -296,60 +239,6 @@ def _histogram(vals: jax.Array, n_valid) -> jax.Array:
     counts = h2.reshape(256).astype(jnp.int32)
     sym = jax.lax.broadcasted_iota(jnp.int32, (256,), 0)
     return counts - jnp.where(sym == 0, n - n_valid, 0)
-
-
-_SWAR_CHUNK = 32              # symbols per SWAR sweep: bounds the (S, CHUNK,
-                              # T*32) popcount intermediate to a few MiB
-
-
-def _histogram_swar(vals: jax.Array, nv: jax.Array) -> jax.Array:
-    """Exact byte histograms of all S zero-padded (T, 128) shards at once ->
-    (S, 256) int32, GEMM-free: SWAR zero-byte test + popcount.
-
-    Bytes pack little-endian 4-per-u32; for each candidate symbol the word
-    is XORed against the symbol splatted to all four byte positions, the
-    classic ``~(((x & 7f..) + 7f..) | x | 7f..)`` zero-byte detector
-    leaves 0x80 exactly at matching bytes, and a ``population_count`` per
-    word counts them.  The per-symbol totals reduce over the word axis as
-    an explicit halving-tree of adds — spelled as slices on purpose:
-    XLA:CPU's reduce lowering over that axis was measured 14x slower than
-    the identical adds in log-depth slice form, while the tree vectorizes
-    flat-out.  Symbols sweep in ``_SWAR_CHUNK`` batches to bound the
-    popcount intermediate (the fused kernel batches K stripes of shards
-    through here).  Bit-identical to :func:`_histogram` by construction —
-    both count exactly; padding bytes are zero (``ops.py`` contract) and
-    are subtracted from bin 0, exactly as there.
-    """
-    S, T, L = vals.shape
-    n = T * L
-    # byte-pack via u8 truncate + bitcast: the 4 strided u32 slices +
-    # shift-or spelling of the same pack measured ~5 ms on the bench
-    # shapes — minor-axis strided loads do not vectorize on XLA:CPU —
-    # while the truncate is one dense pass and the bitcast is free
-    w = jax.lax.bitcast_convert_type(
-        vals.reshape(S, n // 4, 4).astype(jnp.uint8), jnp.uint32
-    )                                                        # (S, n/4)
-    k7f = jnp.uint32(0x7F7F7F7F)
-    k01 = jnp.uint32(0x01010101)
-    outs = []
-    for y0 in range(0, 256, _SWAR_CHUNK):
-        pat = (
-            jax.lax.broadcasted_iota(jnp.uint32, (_SWAR_CHUNK,), 0)
-            + jnp.uint32(y0)
-        ) * k01
-        x = w[:, None, :] ^ pat[None, :, None]
-        z = ~(((x & k7f) + k7f) | x | k7f)                   # 0x80 at matches
-        c = jax.lax.population_count(z)
-        while c.shape[2] > 1:
-            m = c.shape[2]
-            if m % 2:
-                c = jnp.pad(c, ((0, 0), (0, 0), (0, 1)))
-                m += 1
-            c = c[:, :, : m // 2] + c[:, :, m // 2 :]
-        outs.append(c[:, :, 0])
-    counts = jnp.concatenate(outs, axis=1).astype(jnp.int32)
-    sym = jax.lax.broadcasted_iota(jnp.int32, (1, 256), 1)
-    return counts - jnp.where(sym == 0, n - nv, 0)
 
 
 def _enc_step(x, packed, aux, *, division: str = "divide"):
@@ -389,7 +278,7 @@ def _enc_step(x, packed, aux, *, division: str = "divide"):
     if division == "divide":
         q = x // f
     elif division == "rcp32":
-        qh = (x.astype(jnp.float32) * aux).astype(jnp.uint32)
+        qh = (_u32_to_f32(x) * aux).astype(jnp.int32).astype(jnp.uint32)
         r = (x - qh * f).astype(jnp.int32)
         q = (
             qh
@@ -423,25 +312,6 @@ def _dec_step(x, dec_packed, slot2sym):
     return x, s, x < jnp.uint32(RANS_L)
 
 
-def _signed(s, valid):
-    """Decoded symbol byte -> int8 two's complement, zeros on pad lanes."""
-    return jnp.where(valid, s - ((s & 0x80) << 1), 0).astype(jnp.int8)
-
-
-def _valid_positions(T: int, nv):
-    """(T, S, 128) global-byte-index validity mask vs n_valid (S, 1).
-
-    One batched iota compare — the whole n_valid row/lane schedule the
-    old two-loop encoder derived per trip, computed up front so the
-    sequential scan carries no masking at all."""
-    S = nv.shape[0]
-    pos = (
-        jax.lax.broadcasted_iota(jnp.int32, (T, 1, N_LANES), 0) * N_LANES
-        + jax.lax.broadcasted_iota(jnp.int32, (T, 1, N_LANES), 2)
-    )
-    return pos < nv.reshape(1, S, 1)
-
-
 # Identity sentinel symbol entry: f = PROB_SCALE (shift = 12 -> s1 = 11),
 # cum = 0.  _enc_step on it is an exact no-op for every division strategy:
 # emit = (x >> 20) >= PROB_SCALE never fires for a 32-bit state, and
@@ -449,304 +319,447 @@ def _valid_positions(T: int, nv):
 _ENC_SENTINEL = PROB_SCALE | (11 << 13)
 
 
-def rans_encode_body(vals, nv, *, division: str, rows_per_step: int,
-                     histogram: str = "dot"):
-    """Encode-stage dataflow shared by the standalone entropy kernel and the
-    one-launch entropy+seal kernel (``repro.kernels.fused``): histogram ->
-    freq tables -> pregather -> two-phase encode (batched schedule + pure
-    ``lax.scan``).  Pure jnp over values already loaded from refs, so both
-    kernel bodies trace the exact same op sequence — fusing cannot change a
-    single output bit.
+def _u32_to_f32(x):
+    """uint32 -> nearest f32 through two exact int32 halves (Mosaic has no
+    unsigned-to-float convert; one rounding, so it equals ``astype``)."""
+    hi = (x >> jnp.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
 
-    ``vals``: (S, T, 128) int32 symbol bytes in [0, 255]; ``nv``: (S, 1)
-    int32 valid byte counts.  Returns ``(words (S, T, 128) u16, mask
-    (S, T, 128) u8, freq (S, 256) int32, states (S, 128) u32)``.
-    """
-    S, T, _ = vals.shape
 
-    # fused stage 1+2: per-shard histogram -> tables (the stripe is the
-    # block: shards ride the batch axis of every op, so one scan step
-    # feeds S x 128 lanes to the vector unit instead of idling per shard)
-    if histogram == "swar":
-        counts = _histogram_swar(vals, nv)
-    else:
-        counts = jnp.stack(
-            [_histogram(vals[s], nv[s, 0]) for s in range(S)]
+def _pad_shards(a, b_pad: int, fill=0):
+    """Pad the leading shard axis to ``b_pad`` rows with ``fill``."""
+    if a.shape[0] == b_pad:
+        return a
+    pad = [(0, b_pad - a.shape[0])] + [(0, 0)] * (a.ndim - 1)
+    return jnp.pad(a, pad, constant_values=fill)
+
+
+def _shards_padded(B: int, interpret: bool) -> int:
+    """Shards ride the sublanes: Mosaic's lane gathers want whole sublane
+    groups.  The interpreter takes any count, and every padding shard would
+    only add unrolled per-shard work to its trace."""
+    return B if interpret else -(-B // _SUBLANES) * _SUBLANES
+
+
+def _halves(table):
+    """(B, 256) per-shard table -> (2, B, 128): lane-gatherable halves."""
+    return jnp.moveaxis(table.reshape(table.shape[0], 2, N_LANES), 1, 0)
+
+
+def _gather256(halves, idx):
+    """Per-shard 256-entry table lookup: idx (B, 128) int32 in [0, 256)."""
+    li = idx & (N_LANES - 1)
+    lo = jnp.take_along_axis(halves[0], li, axis=1, mode="promise_in_bounds")
+    hi = jnp.take_along_axis(halves[1], li, axis=1, mode="promise_in_bounds")
+    return jnp.where(idx >= N_LANES, hi, lo)
+
+
+def _upper_ones():
+    """(128, 128) bf16 [j <= l]: x @ it is the inclusive lane prefix sum."""
+    return (
+        jax.lax.broadcasted_iota(jnp.int32, (N_LANES, N_LANES), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (N_LANES, N_LANES), 1)
+    ).astype(jnp.bfloat16)
+
+
+def _prefix_sum(flags, upper):
+    """Inclusive prefix count over the lanes of a (B, 128) bool array, as
+    one 0/1 matmul (exact: every sum is <= 128)."""
+    return jax.lax.dot_general(
+        flags.astype(jnp.bfloat16), upper, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+
+
+# ------------------------------------------------------------- histogram
+def _histogram_kernel(codes_ref, acc_ref, *, tile: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    n = 16 * _SUBLANES
+    h_id = jax.lax.broadcasted_iota(jnp.int32, (n, N_LANES), 0) // _SUBLANES
+    diag = (
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) % _SUBLANES
+        == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1) % _SUBLANES
+    )
+    group = min(tile, 32)
+
+    def body(g, acc):
+        v = codes_ref[0, pl.ds(pl.multiple_of(g * group, group), group), :]
+        v = v.astype(jnp.int32) & 0xFF
+        for r0 in range(0, group, _SUBLANES):
+            rows = v[r0:r0 + _SUBLANES]
+            hi = jnp.concatenate([rows >> 4] * 16, axis=0)
+            lo = jnp.concatenate([rows & 15] * 16, axis=0)
+            a = (hi == h_id).astype(jnp.bfloat16)
+            b = (lo == h_id).astype(jnp.bfloat16)
+            c = jax.lax.dot_general(
+                a, b, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc = acc + jnp.where(diag, c, 0.0)
+        return acc
+
+    acc_ref[0] += jax.lax.fori_loop(
+        0, tile // group, body, jnp.zeros((n, n), jnp.float32)
+    )
+
+
+def byte_histogram(codes, n_valid, *, interpret: bool = True):
+    """Exact byte histograms of B zero-padded (T, 128) int8 shards ->
+    (B, 256) int32.  Padding positions past ``n_valid`` are zero bytes (the
+    ``ops.py`` contract), so their count is subtracted from bin 0."""
+    B, T, L = codes.shape
+    tile = min(T, _HIST_TILE)
+    n = 16 * _SUBLANES
+    acc = pl.pallas_call(
+        functools.partial(_histogram_kernel, tile=tile),
+        grid=(B, T // tile),
+        in_specs=[pl.BlockSpec((1, tile, L), lambda b, t: (b, t, 0))],
+        out_specs=pl.BlockSpec((1, n, n), lambda b, t: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="rans_histogram",
+    )(codes)
+    with jax.named_scope("rans_histogram_fold"):
+        blocks = acc.reshape(B, 16, _SUBLANES, 16, _SUBLANES)
+        counts = jnp.einsum("bhrlr->bhl", blocks).reshape(B, 256)
+        counts = counts.astype(jnp.int32)
+        pad = T * L - n_valid.reshape(B, 1)
+        sym = jax.lax.broadcasted_iota(jnp.int32, (1, 256), 1)
+        return counts - jnp.where(sym == 0, pad, 0)
+
+
+# ---------------------------------------------------------------- encode
+def stream_word_cap(T: int) -> int:
+    """Worst-case u16 stream words worth keeping for a T-row shard (any
+    shard emitting more compresses to >= its raw size and is stored raw,
+    so capping the stream here discards only streams the raw-skip select
+    would discard anyway)."""
+    return max(1, (T * N_LANES - 1536) // 2)
+
+
+def _encode_geometry(T: int, tile: int):
+    """(window rows, first back-to-front stream position, buffer rows)."""
+    win = tile + 16
+    p0 = (T + win + 8) * N_LANES
+    return win, p0, T + T // 2 + 2 * win + 16
+
+
+def _encode_kernel(codes_ref, pk_ref, rcp_ref, nv_ref, buf_hbm, lens_ref,
+                   states_ref, x_ref, pos_ref, win_ref, sem, *, tile: int,
+                   n_tiles: int, n_shards: int, win_rows: int, p0: int):
+    j = pl.program_id(0)
+    t = n_tiles - 1 - j                       # row tiles run in reverse
+
+    @pl.when(j == 0)
+    def _init():
+        x_ref[...] = jnp.full(x_ref.shape, RANS_L, jnp.uint32)
+        lens_ref[...] = jnp.zeros(lens_ref.shape, jnp.int32)
+        for s in range(n_shards):
+            pos_ref[s] = jnp.int32(p0)
+
+    # the stream is written back to front: this tile's words land in the
+    # rows just below each shard's write position.  Stage those rows (the
+    # top one may hold words of the previous tile) in a VMEM window.
+    w0 = [
+        ((pos_ref[s] - tile * N_LANES) >> 10) << 3 for s in range(n_shards)
+    ]
+
+    def window_copies(to_hbm: bool):
+        out = []
+        for s in range(n_shards):
+            hbm = buf_hbm.at[s, pl.ds(pl.multiple_of(w0[s], 8), win_rows)]
+            src, dst = (win_ref.at[s], hbm) if to_hbm else (hbm, win_ref.at[s])
+            out.append(pltpu.make_async_copy(src, dst, sem.at[s]))
+        for c in out:
+            c.start()
+        for c in out:
+            c.wait()
+
+    window_copies(False)
+
+    pk = (pk_ref[0], pk_ref[1])
+    rc = (rcp_ref[0], rcp_ref[1])
+    nv = nv_ref[...]
+    B = n_shards
+    lane = jax.lax.broadcasted_iota(jnp.int32, nv.shape, 1)
+    upper = _upper_ones()
+    dest = jax.lax.broadcasted_iota(jnp.int32, (B, N_LANES, 2 * N_LANES), 2)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (B, 2 * N_LANES), 1)
+
+    def row(i, carry):
+        x, lens, offs = carry                 # offs: (B, 1) window offsets
+        r = tile - 1 - i
+        v = codes_ref[r].astype(jnp.int32) & 0xFF          # (B, 128)
+        valid = (t * tile + r) * N_LANES + lane < nv
+        p = jnp.where(valid, _gather256(pk, v), jnp.uint32(_ENC_SENTINEL))
+        x2, x_pre, emit = _enc_step(x, p, _gather256(rc, v), division="rcp32")
+        e = emit.astype(jnp.int32)
+        incl = _prefix_sum(emit, upper)
+        n = incl[:, N_LANES - 1:]                          # words this row
+        o = offs - n
+        lo = o & (N_LANES - 1)
+        # one-hot placement: emitting lane l's word goes to lane
+        # lo + excl[l] of the 256-lane pair of window rows (o >> 7, +1);
+        # its two bytes are exact in bf16 and each sum has one term
+        slot = jnp.where(emit, incl - e + lo, -1)
+        place = (slot[:, :, None] == dest).astype(jnp.bfloat16)
+        word = (x_pre & jnp.uint32(0xFFFF)).astype(jnp.int32)
+        bts = jnp.stack([word & 0xFF, word >> 8], axis=1).astype(jnp.bfloat16)
+        placed = jax.lax.dot_general(
+            bts, place, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)                                # (B, 2, 256)
+        val = placed[:, 0] + (placed[:, 1] << 8)
+        hit = (out_lane >= lo) & (out_lane < lo + n)
+        ro = o >> 7
+        cur = jnp.concatenate(
+            [
+                jnp.concatenate(
+                    [win_ref[s, pl.ds(ro[s, 0], 1), :],
+                     win_ref[s, pl.ds(ro[s, 0] + 1, 1), :]], axis=1,
+                )
+                for s in range(B)
+            ],
+            axis=0,
         )
-    freq = jax.vmap(build_freq_table)(counts)                # (S, 256)
-    packed, mprime, rcp = jax.vmap(build_enc_tables)(freq)
+        merged = jnp.where(hit, val, cur)
+        for s in range(B):
+            win_ref[s, pl.ds(ro[s, 0], 1), :] = merged[s:s + 1, :N_LANES]
+            win_ref[s, pl.ds(ro[s, 0] + 1, 1), :] = merged[s:s + 1, N_LANES:]
+        return x2, lens + e, o
 
-    # pregather the per-position symbol tables once: the scan then reads
-    # only aligned (rows_per_step, S, 128) slices, no gathers on the hot
-    # path
-    flat = vals.reshape(S, T * N_LANES)
-    pk = jnp.moveaxis(
-        jnp.take_along_axis(packed, flat, axis=1).reshape(S, T, N_LANES),
-        0, 1,
-    )                                                        # (T, S, 128)
-    if division == "rcp32":
-        aux = jnp.take_along_axis(rcp, flat, axis=1)
-    elif division == "reciprocal":
-        aux = jnp.take_along_axis(mprime, flat, axis=1)
-    else:
-        aux = None                                           # divide: unused
-    aux = (
-        jnp.moveaxis(aux.reshape(S, T, N_LANES), 0, 1)
-        if aux is not None else pk
+    sub = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    offs0 = jnp.zeros((B, 1), jnp.int32)
+    for s in range(B):
+        offs0 = jnp.where(sub == s, pos_ref[s] - w0[s] * N_LANES, offs0)
+    x, lens, offs = jax.lax.fori_loop(
+        0, tile, row, (x_ref[...], lens_ref[...], offs0)
     )
-
-    # phase 1: the batched schedule.  Swap every invalid position's table
-    # entry for the identity sentinel — the encode step freezes the lane
-    # exactly like the old per-trip ``where`` masking (same frozen states,
-    # words and emissions, bit for bit), but the masking now costs one
-    # vectorized select OUTSIDE the sequential region.  ``aux`` needs no
-    # swap: with f = PROB_SCALE the quotient is multiplied by zero, so any
-    # defined aux value (padding bytes gather symbol 0's, and the table
-    # build clamps f >= 1) yields the same frozen state.  Boundary rows,
-    # fully-padded rows and n_valid = 0 dummy shards all take this path —
-    # there are no dynamic trip counts left to recompute per batch.
-    pk = jnp.where(_valid_positions(T, nv), pk, jnp.uint32(_ENC_SENTINEL))
-
-    # phase 2: minimal-carry scan, rows_per_step rows per trip in reverse
-    # row order.  Carry = lane states only; words/mask leave through the
-    # scan's stacked ys, not a dense dynamic-update chain.
-    R = rows_per_step
-    pkc = pk.reshape(T // R, R, S, N_LANES)
-    auxc = aux.reshape(T // R, R, S, N_LANES)
-
-    def step(x, xs):
-        pc, ac = xs
-        ws, ms = [None] * R, [None] * R
-        for k in range(R - 1, -1, -1):
-            x, x_pre, emit = _enc_step(x, pc[k], ac[k], division=division)
-            ws[k] = (x_pre & jnp.uint32(0xFFFF)).astype(jnp.uint16)
-            ms[k] = emit.astype(jnp.uint8)
-        return x, (jnp.stack(ws), jnp.stack(ms))
-
-    x0 = jnp.full((S, N_LANES), RANS_L, jnp.uint32)
-    x, (w_rev, m_rev) = jax.lax.scan(step, x0, (pkc[::-1], auxc[::-1]))
-    words = jnp.moveaxis(w_rev[::-1].reshape(T, S, N_LANES), 1, 0)
-    mask = jnp.moveaxis(m_rev[::-1].reshape(T, S, N_LANES), 1, 0)
-    return words, mask, freq, x
+    window_copies(True)
+    x_ref[...] = x
+    states_ref[...] = x
+    lens_ref[...] = lens
+    for s in range(B):
+        pos_ref[s] = w0[s] * N_LANES + offs[s, 0]
 
 
-def _encode_kernel(codes_ref, nvalid_ref, words_ref, mask_ref, freq_ref,
-                   state_ref, *, division: str, rows_per_step: int,
-                   histogram: str):
-    vals = (codes_ref[...].astype(jnp.int32)) & 0xFF         # (S, T, 128)
-    nv = nvalid_ref[...]                                     # (S, 1)
-    words, mask, freq, states = rans_encode_body(
-        vals, nv, division=division, rows_per_step=rows_per_step,
-        histogram=histogram,
-    )
-    words_ref[...] = words
-    mask_ref[...] = mask
-    freq_ref[...] = freq
-    state_ref[...] = states
+def rans_encode_pallas(codes, n_valid, *, interpret: bool = True):
+    """Encode B shards: histogram -> tables -> coding loop (see module doc).
 
-
-def _decode_kernel(stream_ref, freq_ref, state_ref, nvalid_ref, codes_ref,
-                   *, rows_per_step: int):
-    """Version-1 decode: row-major word stream, prefix-sum read pointer.
-
-    Mirrors the encoder's two-phase shape: the whole row/lane validity
-    schedule is one batched iota compare (phase 1), and the sequential
-    region is a minimal-carry ``lax.scan`` over the rows — carry = (lane
-    states, stream read pointer), decoded rows leave through the scan's
-    stacked outputs.  The decode consumes rows forward (the encoder ran
-    them in reverse).  Invalid lanes renorm-mask to zero consumption, so
-    boundary rows, fully-padded rows and n_valid = 0 shards ride the same
-    body with no dynamic trip counts.
-    """
-    stream = stream_ref[...]                                 # (S, W) u16
-    S, W = stream.shape
-    freq = freq_ref[...]                                     # (S, 256) int32
-    T = codes_ref.shape[1]
-    nv = nvalid_ref[...]
-    dec_packed = jax.vmap(build_dec_table)(freq)
-    slot2sym = jax.vmap(slot_to_symbol)(freq)
-
-    R = rows_per_step
-    vc = _valid_positions(T, nv).reshape(T // R, R, S, N_LANES)
-
-    def step(carry, vck):
-        x, base = carry
-        rows = [None] * R
-        for k in range(R):
-            valid = vck[k]
-            x2, sym, need = _dec_step(x, dec_packed, slot2sym)
-            need = need & valid
-            sgn = jnp.where(
-                valid, (sym - ((sym & 0x80) << 1)).astype(jnp.int8), 0
-            )
-            csum = jnp.cumsum(need.astype(jnp.int32), axis=-1)
-            pos = base[:, None] + csum - need.astype(jnp.int32)
-            w = jnp.take_along_axis(
-                stream, jnp.minimum(pos, W - 1), axis=1
-            ).astype(jnp.uint32)
-            x2 = jnp.where(need, (x2 << jnp.uint32(16)) | w, x2)
-            x = jnp.where(valid, x2, x)
-            base = base + csum[:, N_LANES - 1]
-            rows[k] = sgn
-        return (x, base), jnp.stack(rows)
-
-    carry = (state_ref[...], jnp.zeros((S,), jnp.int32))
-    _, out = jax.lax.scan(step, carry, vc)
-    codes_ref[...] = jnp.moveaxis(out.reshape(T, S, N_LANES), 1, 0)
-
-
-def _decode_kernel_v0(stream_ref, freq_ref, state_ref, nvalid_ref, codes_ref,
-                      *, rows_per_step: int):
-    """Version-0 decode twin: lane-major words, per-lane read pointers.
-    Same minimal-carry scan shape as the v1 decoder — carry = (lane
-    states, per-lane word pointers)."""
-    lane_words = stream_ref[...]                             # (S, T, 128) u16
-    S, T, _ = lane_words.shape
-    freq = freq_ref[...]
-    nv = nvalid_ref[...]
-    dec_packed = jax.vmap(build_dec_table)(freq)
-    slot2sym = jax.vmap(slot_to_symbol)(freq)
-
-    R = rows_per_step
-    vc = _valid_positions(T, nv).reshape(T // R, R, S, N_LANES)
-
-    def step(carry, vck):
-        x, ptr = carry
-        rows = [None] * R
-        for k in range(R):
-            valid = vck[k]
-            x2, sym, need = _dec_step(x, dec_packed, slot2sym)
-            need = need & valid
-            sgn = jnp.where(
-                valid, (sym - ((sym & 0x80) << 1)).astype(jnp.int8), 0
-            )
-            w = jnp.take_along_axis(
-                lane_words, jnp.minimum(ptr, T - 1)[:, None, :], axis=1
-            )[:, 0].astype(jnp.uint32)
-            x2 = jnp.where(need, (x2 << jnp.uint32(16)) | w, x2)
-            x = jnp.where(valid, x2, x)
-            ptr = ptr + need.astype(jnp.int32)
-            rows[k] = sgn
-        return (x, ptr), jnp.stack(rows)
-
-    carry = (state_ref[...], jnp.zeros((S, N_LANES), jnp.int32))
-    _, out = jax.lax.scan(step, carry, vc)
-    codes_ref[...] = jnp.moveaxis(out.reshape(T, S, N_LANES), 1, 0)
-
-
-def _rows_per_step(rows_per_step, interpret: bool, rows: int) -> int:
-    """Static scan-step width: 1 row/trip under interpret (many tiny
-    ops beat few fat fused bodies on CPU), an (N_GROUPS, 128) sublane tile
-    per trip otherwise (one vreg per step on TPU).  Pure schedule — the
-    output bits are identical for every choice."""
-    if rows_per_step is None:
-        rows_per_step = 1 if interpret else N_GROUPS
-    if rows % rows_per_step:
-        raise ValueError(f"{rows} rows not a multiple of {rows_per_step}")
-    return rows_per_step
-
-
-def _histogram_impl(histogram, interpret: bool) -> str:
-    """Default the histogram strategy per backend: SWAR popcount under
-    interpret (the CPU GEMM runs 16-wide tiles at a quarter of peak),
-    one-hot matmul otherwise (the MXU eats it).  Bit-identical either
-    way — both are exact counts."""
-    if histogram is None:
-        histogram = "swar" if interpret else "dot"
-    if histogram not in ("dot", "swar"):
-        raise ValueError(f"unknown histogram strategy {histogram!r}")
-    return histogram
-
-
-def rans_encode_pallas(codes, n_valid, *, division: str = "divide",
-                       rows_per_step: int = None, histogram: str = None,
-                       interpret: bool = True):
-    """Encode all S shards of a stripe in one launch (the stripe is the
-    kernel block; shards stack on the batch axis of every vector op).
-
-    codes: (S, T, 128) int8 payload rows, zero-padded (the histogram's
-    pad correction requires the padding bytes to BE zero — ``ops.py``
+    codes: (B, T, 128) int8 payload rows, zero-padded (the histogram's pad
+    correction requires the padding bytes to BE zero — ``ops.py``
     guarantees it); T % T_TILE == 0.
-    n_valid: (S, 1) int32 valid byte count per shard — positions past it
-    are padding and are excluded from both the histogram and the coding
-    loop (their lanes idle, costing zero stream bytes).
-    division: "reciprocal" (all-integer Granlund-Montgomery mulhi — the
-    interpret/CPU default; u32 udiv scalarizes on x86), "rcp32"
-    (error-repaired f32 reciprocal — the TPU default; Mosaic has no
-    integer divide) or "divide" (hardware udiv); the streams are
-    bit-identical in all three.
-    histogram: "swar" (popcount sweep — interpret/CPU default) or "dot"
-    (one-hot matmul — TPU default); exact counts, bit-identical streams
-    either way.
-    Returns (words (S, T, 128) uint16, mask (S, T, 128) uint8,
-    freq (S, 256) int32, states (S, 128) uint32): the dense emission buffer
-    + per-row emission mask (rank-select compacted by the caller), the
-    per-shard frequency tables, and the final lane states the decoder
-    starts from.
+    n_valid: (B, 1) int32 valid byte count per shard — positions past it
+    are padding: they are excluded from the histogram and their lanes idle,
+    costing zero stream bytes.
+    Returns (words (B, cap) uint16, n_words (B,) int32, lane_lens (B, 128)
+    int32, freq (B, 256) int32, states (B, 128) uint32): each shard's
+    emitted words in row-major decoder-read order (zero past n_words; cap
+    = :func:`stream_word_cap`), its per-lane word counts, frequency table
+    and the final lane states the decoder starts from.
     """
-    S, T, L = codes.shape
+    B, T, L = codes.shape
     if L != N_LANES:
         raise ValueError(f"expected {N_LANES} lanes, got {L}")
     if T % T_TILE:
         raise ValueError(f"rows {T} not a multiple of {T_TILE}")
-    if division not in ("divide", "rcp32", "reciprocal"):
-        raise ValueError(f"unknown division strategy {division!r}")
-    rps = _rows_per_step(rows_per_step, interpret, T)
-    hist = _histogram_impl(histogram, interpret)
-    return pl.pallas_call(
-        functools.partial(_encode_kernel, division=division,
-                          rows_per_step=rps, histogram=hist),
+    Bp = _shards_padded(B, interpret)
+    # rows per grid step: a power of two (so it divides T) sized to the
+    # block budget; a pure schedule, the outputs do not depend on it
+    budget = max(T_TILE, _TILE_ELEMS // (Bp * N_LANES))
+    tile = min(T, 1 << (budget.bit_length() - 1))
+    win_rows, p0, buf_rows = _encode_geometry(T, tile)
+    codes_p = _pad_shards(codes, Bp)
+    nv = _pad_shards(n_valid.reshape(B, 1).astype(jnp.int32), Bp)
+    counts = byte_histogram(codes_p, nv, interpret=interpret)
+    with jax.named_scope("rans_tables"):
+        freq = jax.vmap(build_freq_table)(counts)
+        packed, _, rcp = jax.vmap(build_enc_tables)(freq)
+        codes_t = jnp.swapaxes(codes_p, 0, 1)                # (T, Bp, 128)
+        nv_b = jnp.broadcast_to(nv, (Bp, N_LANES))
+    n_tiles = T // tile
+    rev = lambda j: (n_tiles - 1 - j, 0, 0)
+    whole = pl.BlockSpec((2, Bp, N_LANES), lambda j: (0, 0, 0))
+    lanes = pl.BlockSpec((Bp, N_LANES), lambda j: (0, 0))
+    buf, lens, states = pl.pallas_call(
+        functools.partial(_encode_kernel, tile=tile, n_tiles=n_tiles,
+                          n_shards=Bp, win_rows=win_rows, p0=p0),
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((tile, Bp, N_LANES), rev), whole, whole, lanes],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY), lanes, lanes],
         out_shape=[
-            jax.ShapeDtypeStruct((S, T, N_LANES), jnp.uint16),
-            jax.ShapeDtypeStruct((S, T, N_LANES), jnp.uint8),
-            jax.ShapeDtypeStruct((S, 256), jnp.int32),
-            jax.ShapeDtypeStruct((S, N_LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((Bp, buf_rows, N_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, N_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, N_LANES), jnp.uint32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((Bp, N_LANES), jnp.uint32),
+            pltpu.SMEM((Bp,), jnp.int32),
+            pltpu.VMEM((Bp, win_rows, N_LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((Bp,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
-    )(codes, n_valid)
+        name="rans_encode",
+    )(codes_t, _halves(packed), _halves(rcp), nv_b)
+    with jax.named_scope("rans_stream_slice"):
+        cap = stream_word_cap(T)
+        n_words = lens.sum(axis=1)
+        words = jax.vmap(
+            lambda b, p: jax.lax.dynamic_slice(b, (p,), (cap,))
+        )(buf.reshape(Bp, -1), p0 - n_words)
+        k = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
+        words = jnp.where(k < n_words[:, None], words, 0).astype(jnp.uint16)
+    return words[:B], n_words[:B], lens[:B], freq[:B], states[:B]
+
+
+# ---------------------------------------------------------------- decode
+def _decode_kernel(stream_hbm, cum_ref, states_ref, nv_ref, codes_ref,
+                   x_ref, base_ref, win_ref, sem, *, tile: int, n_shards: int,
+                   win_rows: int):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _init():
+        x_ref[...] = states_ref[...]
+        for s in range(n_shards):
+            base_ref[s] = jnp.int32(0)
+
+    # refill each shard's stream window: rows [w0, w0 + win_rows) with w0
+    # the read pointer's row, aligned down to a sublane group
+    w0 = [(base_ref[s] >> 10) << 3 for s in range(n_shards)]
+    copies = [
+        pltpu.make_async_copy(
+            stream_hbm.at[s, pl.ds(pl.multiple_of(w0[s], 8), win_rows)],
+            win_ref.at[s], sem.at[s],
+        )
+        for s in range(n_shards)
+    ]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+
+    cum = (cum_ref[0], cum_ref[1])               # inclusive cumulative freqs
+    nv = nv_ref[...]
+    B = n_shards
+    shape = nv.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    upper = _upper_ones()
+
+    def row(r, carry):
+        x, offs = carry                       # offs: (B, 1) window offsets
+        slot = (x & jnp.uint32(PROB_SCALE - 1)).astype(jnp.int32)
+        # symbol = #{k : cum_incl[k] <= slot}, by branchless binary search
+        sym = jnp.zeros(shape, jnp.int32)
+        for b in (128, 64, 32, 16, 8, 4, 2, 1):
+            t = sym + b
+            sym = jnp.where(_gather256(cum, t - 1) <= slot, t, sym)
+        c_lo = jnp.where(sym > 0, _gather256(cum, jnp.maximum(sym - 1, 0)), 0)
+        c_hi = _gather256(cum, sym)
+        x2 = (c_hi - c_lo).astype(jnp.uint32) * (x >> jnp.uint32(PROB_BITS)) + (
+            slot - c_lo
+        ).astype(jnp.uint32)
+        valid = (j * tile + r) * N_LANES + lane < nv
+        need = (x2 < jnp.uint32(RANS_L)) & valid
+        incl = _prefix_sum(need, upper)
+        # the renormalizing lanes take the next words in lane order: lane l
+        # reads word offs + excl[l], from window row (offs >> 7) or the next
+        q = (offs & (N_LANES - 1)) + incl - need.astype(jnp.int32)
+        wr = offs >> 7
+        rows_a = jnp.concatenate(
+            [win_ref[s, pl.ds(wr[s, 0], 1), :] for s in range(B)], axis=0
+        )
+        rows_b = jnp.concatenate(
+            [win_ref[s, pl.ds(wr[s, 0] + 1, 1), :] for s in range(B)], axis=0
+        )
+        li = q & (N_LANES - 1)
+        w = jnp.where(
+            q >= N_LANES,
+            jnp.take_along_axis(rows_b, li, axis=1, mode="promise_in_bounds"),
+            jnp.take_along_axis(rows_a, li, axis=1, mode="promise_in_bounds"),
+        ).astype(jnp.uint32)
+        x2 = jnp.where(need, (x2 << jnp.uint32(16)) | w, x2)
+        codes_ref[r] = jnp.where(valid, sym - ((sym & 0x80) << 1), 0).astype(
+            jnp.int8
+        )
+        return jnp.where(valid, x2, x), offs + incl[:, N_LANES - 1:]
+
+    sub = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    offs0 = jnp.zeros((B, 1), jnp.int32)
+    for s in range(B):
+        offs0 = jnp.where(sub == s, base_ref[s] - w0[s] * N_LANES, offs0)
+    x, offs = jax.lax.fori_loop(0, tile, row, (x_ref[...], offs0))
+    x_ref[...] = x
+    for s in range(B):
+        base_ref[s] = w0[s] * N_LANES + offs[s, 0]
 
 
 def rans_decode_pallas(stream, freq, states, n_valid, *, rows: int,
-                       rows_per_step: int = None, interpret: bool = True):
-    """Version-1 decode twin: flat row-major word streams -> original bytes.
+                       interpret: bool = True):
+    """Version-1 decode: flat row-major word streams -> original bytes.
 
-    stream: (S, W) uint16 — each shard's words in global decoder-read order
-    (tails past the shard's word count are never consumed).  The decoder
-    advances a single per-shard stream pointer; per sub-step, the lanes
-    that renormalize take the next popcount(need) words in lane order via
-    an in-register prefix sum — no per-lane offset table is parsed.
-    freq: (S, 256) int32 tables; states: (S, 128) uint32 initial lane
-    states; n_valid: (S, 1) int32 — must equal the encoder's.
-    Returns (S, rows, 128) int8 decoded payload rows, zeros past n_valid.
+    stream: (B, W) uint16 — each shard's words in global decoder-read order
+    (tails past the shard's word count are never consumed).  freq: (B, 256)
+    int32 tables; states: (B, 128) uint32 initial lane states; n_valid:
+    (B, 1) int32 — must equal the encoder's.
+    Returns (B, rows, 128) int8 decoded payload rows, zeros past n_valid.
     """
-    S, W = stream.shape
+    B, W = stream.shape
     if rows % T_TILE:
         raise ValueError(f"rows {rows} not a multiple of {T_TILE}")
-    rps = _rows_per_step(rows_per_step, interpret, rows)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, rows_per_step=rps),
-        out_shape=jax.ShapeDtypeStruct((S, rows, N_LANES), jnp.int8),
+    Bp = _shards_padded(B, interpret)
+    tile = min(rows, _DEC_TILE)
+    win_rows = tile + 16
+    with jax.named_scope("rans_decode_tables"):
+        # dummy shards decode against a degenerate-but-valid table (symbol
+        # 0 owns the whole range); n_valid = 0 idles their lanes
+        freq_p = jnp.concatenate(
+            [freq.astype(jnp.int32),
+             jnp.zeros((Bp - B, 256), jnp.int32).at[:, 0].set(PROB_SCALE)]
+        )
+        cum = jnp.cumsum(freq_p, axis=1)
+        n_rows = -(-W // N_LANES) + win_rows + 8
+        words = jnp.pad(
+            stream.astype(jnp.int32), ((0, Bp - B), (0, n_rows * N_LANES - W))
+        ).reshape(Bp, n_rows, N_LANES)
+        st = _pad_shards(states.astype(jnp.uint32), Bp, RANS_L)
+        nv = jnp.broadcast_to(
+            _pad_shards(n_valid.reshape(B, 1).astype(jnp.int32), Bp),
+            (Bp, N_LANES),
+        )
+    whole = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
+    codes = pl.pallas_call(
+        functools.partial(_decode_kernel, tile=tile, n_shards=Bp,
+                          win_rows=win_rows),
+        grid=(rows // tile,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            whole((2, Bp, N_LANES)),
+            whole((Bp, N_LANES)),
+            whole((Bp, N_LANES)),
+        ],
+        out_specs=pl.BlockSpec((tile, Bp, N_LANES), lambda j: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, Bp, N_LANES), jnp.int8),
+        scratch_shapes=[
+            pltpu.VMEM((Bp, N_LANES), jnp.uint32),
+            pltpu.SMEM((Bp,), jnp.int32),
+            pltpu.VMEM((Bp, win_rows, N_LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((Bp,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
-    )(stream, freq, states, n_valid)
-
-
-def rans_decode_pallas_v0(lane_words, freq, states, n_valid, *,
-                          rows_per_step: int = None, interpret: bool = True):
-    """Version-0 decode twin: per-lane word streams + header tables.
-
-    lane_words: (S, T, 128) uint16 — word j of lane l at [s, j, l] (the
-    caller re-gathers the flat lane-major stream into this layout; tails
-    past each lane's length are never consumed).  Kept so PR-4-era archives
-    and checkpoints stay decodable across the row-major format change.
-    """
-    S, T, L = lane_words.shape
-    if L != N_LANES:
-        raise ValueError(f"expected {N_LANES} lanes, got {L}")
-    if T % T_TILE:
-        raise ValueError(f"rows {T} not a multiple of {T_TILE}")
-    rps = _rows_per_step(rows_per_step, interpret, T)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel_v0, rows_per_step=rps),
-        out_shape=jax.ShapeDtypeStruct((S, T, N_LANES), jnp.int8),
-        interpret=interpret,
-    )(lane_words, freq, states, n_valid)
+        name="rans_decode",
+    )(words, _halves(cum), st, nv)
+    return jnp.swapaxes(codes, 0, 1)[:B]

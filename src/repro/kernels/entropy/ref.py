@@ -41,6 +41,7 @@ __all__ = [
     "STAGED_PASSES",
     "N_STAGED_PASSES",
     "rans_encode_ref",
+    "compact_ref",
     "rans_decode_ref",
     "rans_decode_ref_v0",
 ]
@@ -96,6 +97,21 @@ def rans_encode_ref(codes: jax.Array, n_valid: jax.Array,
     words = jnp.swapaxes(w_rev[::-1], 0, 1)                  # back to (S, T, 128)
     mask = jnp.swapaxes(m_rev[::-1], 0, 1)
     return words, mask, freq, states
+
+
+def compact_ref(words: jax.Array, mask: jax.Array, cap: int):
+    """Stream compaction oracle: the emitted words of (S, T, 128) in
+    row-major decoder-read order, by a stable sort on the emission flags.
+    Returns (words (S, cap) uint16 zero past n_words, n_words (S,),
+    lane_lens (S, 128) per-lane word counts)."""
+    S, T, L = words.shape
+    flags = mask.reshape(S, T * L) != 0
+    order = jnp.argsort(jnp.logical_not(flags), axis=1, stable=True)[:, :cap]
+    w = jnp.take_along_axis(words.reshape(S, T * L), order, axis=1)
+    n_words = flags.sum(axis=1).astype(jnp.int32)
+    k = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    w = jnp.where(k < n_words[:, None], w, 0).astype(jnp.uint16)
+    return w, n_words, mask.astype(jnp.int32).sum(axis=1)
 
 
 def rans_decode_ref(

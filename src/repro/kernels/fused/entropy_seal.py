@@ -1,39 +1,32 @@
-"""Pallas TPU kernel: one-launch archival — rANS entropy encode fused into
-the seal datapath, batched over K coalesced stripes per launch.
+"""The archival write chain on the device: rANS entropy encode, stream pack,
+adaptive raw-skip, ChaCha20 XOR-seal and RAID-5 P / RAID-6 Q, for a batch
+of K coalesced stripes.
 
-One launch takes a batch of B = K * S zero-padded shard payloads straight
-through codes -> histogram -> freq tables -> interleaved rANS ->
-rank-select stream pack -> adaptive raw-skip select -> ChaCha20 keystream ->
-XOR-seal -> RAID-5 P / RAID-6 Q, with the packed word streams living only in
-VMEM: the HBM roundtrip the chained ``kernels/entropy`` -> ``kernels/seal``
-datapath paid between its two launches (write streams, read streams) is
-gone, and so is the second launch's dispatch.
+Per batch the write path launches, in order (every stage compiled for the
+device, each under a stable name):
 
-The encode stage is the *same traced op sequence* as the standalone entropy
-kernel (``rans.rans_encode_body`` is shared), the pack is the same
-rank-select gather (``ops._pack_rank_impl`` / ``_pack_bytes_impl``), and the
-keystream/parity stages share their producers with the seal kernel
-(``seal.keystream_batch`` / ``seal._gf_mul_const_u32``) — so fusing cannot
-change a single stored bit vs the chained path.
+  1. ``rans_histogram`` — Pallas, per-shard byte histogram (MXU);
+  2. ``rans_tables`` — XLA, frequency and encode tables (256-wide);
+  3. ``rans_encode`` — Pallas, the interleaved coding loop over row tiles,
+     which also compacts each row's emitted words into the shard's
+     row-major stream (written back to front through a VMEM window);
+  4. ``rans_pack`` — XLA, stream slice + header serialization, the
+     adaptive raw-skip select and the little-endian u32 packing;
+  5. ``seal_stripes`` — Pallas, keystream + XOR-seal + valid-length mask +
+     P/Q folds for all K stripes (``kernels/seal``).
 
-Multi-stripe batching has two schedules, bit-identical by construction:
-
-* interpret / CPU (the CI path): the whole K-stripe batch is ONE kernel
-  block — every loop op runs over (K*S, 128) operands, so the per-op
-  dispatch overhead that dominates interpret-mode runtime amortizes K-fold
-  (this is what pushes ``vs_host_speed`` past 1.0 in the committed bench).
-* TPU (``grid_stripes=True``, the non-interpret default): stripes ride the
-  launch grid axis, one stripe's (S, T, 128) block per step, and Pallas
-  double-buffers the revisited in/out blocks — stripe i's encode overlaps
-  stripe i-1's sealed/parity writeback, still one launch total.
+So the packed streams visit HBM between the coder and the seal: three
+Pallas launches per stripe batch, whatever K is, with XLA glue between
+them.  Every stage is the same computation as the chained
+``kernels/entropy`` -> ``kernels/seal`` path, so the stored bits are
+identical to it (and to ``ref.py``).
 
 Capacity invariants (why fixed-size outputs lose nothing):
 
 * stream word cap: a shard whose emission count reaches
   ``(T*128 - HEADER_BYTES) // 2`` words compresses to >= its raw size and
-  is stored raw, so capping the pack there discards only streams the
-  raw-skip select would discard anyway (the packed words are
-  position-exact for ANY cap — see ``_pack_rank_impl``).
+  is stored raw, so capping the stream there discards only streams the
+  raw-skip select would discard anyway.
 * sealed rows cap: the stored body (raw or v1 stream) of a T-row shard
   never exceeds T*128 bytes — the v1 stream is exactly T*128 bytes at the
   raw-skip boundary — so ``pad_rows_for(T*32)`` rows always cover it, and
@@ -43,33 +36,19 @@ Capacity invariants (why fixed-size outputs lose nothing):
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.entropy.ops import (
-    HEADER_BYTES,
-    _pack_bytes_impl,
-    _pack_rank_impl,
-    stream_word_cap,
-)
+from repro.kernels import le_words
+from repro.kernels.entropy.ops import HEADER_BYTES, stream_words
 from repro.kernels.entropy.rans import (
     N_LANES,
     T_TILE,
-    _histogram_impl,
-    _rows_per_step,
-    rans_encode_body,
+    rans_encode_pallas,
+    stream_word_cap,
 )
 from repro.kernels.seal.ops import pad_rows_for
-from repro.kernels.seal.seal import (
-    LANES,
-    ROW_BYTES,
-    _gf_mul_const_u32,
-    keystream_batch,
-)
+from repro.kernels.seal.seal import LANES, seal_words_pallas
 
 # ``stream_word_cap`` moved next to the pack it sizes (entropy ops); the
 # fused module re-exports it because the seal-side capacity story lives here
@@ -82,106 +61,19 @@ def seal_rows_cap(T: int) -> int:
     return pad_rows_for(T * N_LANES // 4)
 
 
-def _entropy_seal_kernel(
-    codes_ref, nvalid_ref, keys_ref, nonces_ref, qcoef_ref,
-    sealed_ref, nwords_ref, *parity_refs,
-    n_shards: int, division: str, rows_per_step: int, histogram: str,
-):
-    B, T, L = codes_ref.shape
-    R_cap = sealed_ref.shape[1]
-    vals = (codes_ref[...].astype(jnp.int32)) & 0xFF         # (B, T, 128)
-    nv = nvalid_ref[...]                                     # (B, 1)
-
-    # stage 1: interleaved rANS encode — the standalone entropy kernel's
-    # exact op sequence (shared body), K*S shards on the batch axis
-    words, mask, freq, states = rans_encode_body(
-        vals, nv, division=division, rows_per_step=rows_per_step,
-        histogram=histogram,
-    )
-
-    # stage 2: rank-select pack straight into v1 stream bytes, in VMEM —
-    # the packed word streams never touch HBM.  ``tiered``: the static cap
-    # is the raw-skip worst case (~2.5x typical emissions), so the pack
-    # runs at half width whenever the batch's true counts allow
-    src, n_words, lane_lens = _pack_rank_impl(
-        mask, cap=stream_word_cap(T), tiered=True
-    )
-    stream_u8 = _pack_bytes_impl(words, src, n_words, lane_lens, freq, states)
-
-    # stage 3: adaptive raw-skip select (n_words is the TRUE emission
-    # count — ``_pack_rank_impl`` counts before capping — so the condition
-    # is exactly the chained host-side one).  Both branches are zero past
-    # their stored length: raw by the ops-layer padding contract, the
-    # stream because words at k >= n_words are zeroed in the pack.
-    n_raw = nv[:, 0]
-    n_comp = HEADER_BYTES + 2 * n_words
-    is_raw = n_comp >= n_raw
-    buf = T * L
-    raw_u8 = vals.reshape(B, buf).astype(jnp.uint8)
-    body_u8 = jnp.where(is_raw[:, None], raw_u8, stream_u8[:, :buf])
-    pad = R_cap * ROW_BYTES - buf
-    if pad:
-        body_u8 = jnp.pad(body_u8, ((0, 0), (0, pad)))
-
-    # stage 4: pack u8 -> uint32 little-endian lanes (the seal layout)
-    b4 = body_u8.reshape(B, R_cap, L, 4).astype(jnp.uint32)
-    packed = (
-        b4[..., 0]
-        | (b4[..., 1] << jnp.uint32(8))
-        | (b4[..., 2] << jnp.uint32(16))
-        | (b4[..., 3] << jnp.uint32(24))
-    )
-
-    # stage 5: ChaCha20 keystream + XOR-seal + stored-length mask
-    ks = keystream_batch(keys_ref[...], nonces_ref[...], R_cap)
-    stored = jnp.where(is_raw, n_raw, n_comp)
-    n_sealed = -(-stored // 4)                               # stored u32 words
-    widx = (
-        jax.lax.broadcasted_iota(jnp.int32, (1, R_cap, L), 1) * L
-        + jax.lax.broadcasted_iota(jnp.int32, (1, R_cap, L), 2)
-    )
-    sealed = jnp.where(
-        widx < n_sealed[:, None, None], packed ^ ks, jnp.uint32(0)
-    )
-    sealed_ref[...] = sealed
-    nwords_ref[...] = n_words[:, None]
-
-    # stage 6: per-stripe RAID parity — XOR folds over each stripe's S
-    # shards (order-free, so any slicing/sharding of the fold is exact)
-    if parity_refs:
-        K = B // n_shards
-        g = sealed.reshape(K, n_shards, R_cap, L)
-        p = g[:, 0]
-        for s in range(1, n_shards):
-            p = p ^ g[:, s]
-        parity_refs[0][...] = p
-        if len(parity_refs) > 1:
-            qc = qcoef_ref[...].reshape(K, n_shards)
-            q = _gf_mul_const_u32(g[:, 0], qc[:, 0][:, None, None])
-            for s in range(1, n_shards):
-                q = q ^ _gf_mul_const_u32(g[:, s], qc[:, s][:, None, None])
-            parity_refs[1][...] = q
-
-
 def entropy_seal_pallas(
     codes, n_valid, keys, nonces, q_coef, *, n_shards: int,
-    parity: str = "raid6", division: str = "divide",
-    rows_per_step: Optional[int] = None, histogram: Optional[str] = None,
-    grid_stripes: Optional[bool] = None, interpret: bool = True,
+    parity: str = "raid6", interpret: bool = True,
 ):
-    """One launch: rANS-encode, pack, ChaCha20-XOR-seal and parity-fold a
-    batch of K = B // n_shards coalesced stripes.
+    """rANS-encode, pack, ChaCha20-XOR-seal and parity-fold a batch of
+    K = B // n_shards coalesced stripes (see the module doc for the
+    launches).
 
     codes: (B, T, 128) int8 payload rows, zero-padded (stripes contiguous:
     shard s of stripe k is row k*n_shards + s); n_valid: (B, 1) int32 RAW
-    byte counts (pre-compression — the kernel decides raw-skip itself);
+    byte counts (pre-compression — the raw-skip decision is made here);
     keys (B, 8) / nonces (B, 3) / q_coef (B, 1) uint32 per-shard session
     material and RAID-6 GF coefficients.
-
-    ``grid_stripes`` picks the multi-stripe schedule (None = not
-    interpret): False runs the batch as one fat block (interpret/CPU —
-    amortizes per-op dispatch), True puts stripes on the launch grid with
-    double-buffered blocks (TPU).  Pure schedule; outputs are identical.
 
     Returns (sealed (B, R_cap, 128) u32, n_words (B, 1) int32 emitted rANS
     word counts, p (K, R_cap, 128) u32 | None, q (K, R_cap, 128) u32 |
@@ -195,53 +87,30 @@ def entropy_seal_pallas(
         raise ValueError(f"rows {T} not a multiple of {T_TILE}")
     if n_shards <= 0 or B % n_shards:
         raise ValueError(f"batch of {B} shards not a multiple of {n_shards}")
-    if division not in ("divide", "rcp32", "reciprocal"):
-        raise ValueError(f"unknown division strategy {division!r}")
     if parity not in ("none", "raid5", "raid6"):
         raise ValueError(f"unknown parity {parity!r}")
-    K = B // n_shards
     R_cap = seal_rows_cap(T)
-    rps = _rows_per_step(rows_per_step, interpret, T)
-    if grid_stripes is None:
-        grid_stripes = not interpret
-    kern = functools.partial(
-        _entropy_seal_kernel,
-        n_shards=n_shards, division=division, rows_per_step=rps,
-        histogram=_histogram_impl(histogram, interpret),
+
+    words, n_words, lane_lens, freq, states = rans_encode_pallas(
+        codes, n_valid, interpret=interpret
     )
-    n_parity = {"none": 0, "raid5": 1, "raid6": 2}[parity]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, R_cap, LANES), jnp.uint32),
-        jax.ShapeDtypeStruct((B, 1), jnp.int32),
-    ] + [
-        jax.ShapeDtypeStruct((K, R_cap, LANES), jnp.uint32)
-    ] * n_parity
-    if not grid_stripes or K == 1:
-        outs = pl.pallas_call(kern, out_shape=out_shape, interpret=interpret)(
-            codes, n_valid, keys, nonces, q_coef
-        )
-    else:
-        S = n_shards
-        outs = pl.pallas_call(
-            kern,
-            grid=(K,),
-            in_specs=[
-                pl.BlockSpec((S, T, L), lambda k: (k, 0, 0)),
-                pl.BlockSpec((S, 1), lambda k: (k, 0)),
-                pl.BlockSpec((S, 8), lambda k: (k, 0)),
-                pl.BlockSpec((S, 3), lambda k: (k, 0)),
-                pl.BlockSpec((S, 1), lambda k: (k, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((S, R_cap, LANES), lambda k: (k, 0, 0)),
-                pl.BlockSpec((S, 1), lambda k: (k, 0)),
-            ] + [
-                pl.BlockSpec((1, R_cap, LANES), lambda k: (k, 0, 0))
-            ] * n_parity,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(codes, n_valid, keys, nonces, q_coef)
-    sealed, n_words = outs[0], outs[1]
-    p = outs[2] if n_parity else None
-    q = outs[3] if n_parity > 1 else None
-    return sealed, n_words, p, q
+    with jax.named_scope("rans_pack"):
+        stream = stream_words(words, lane_lens, freq, states)
+        raw = le_words(codes.reshape(B, T * L).astype(jnp.int32) & 0xFF, 4)
+        # adaptive raw-skip select (n_words is the TRUE emission count, not
+        # capped).  Both branches are zero past their stored length: raw by
+        # the ops-layer padding contract, the stream because words past
+        # n_words are zeroed.
+        n_raw = n_valid.reshape(B)
+        n_comp = HEADER_BYTES + 2 * n_words
+        is_raw = n_comp >= n_raw
+        n_body = T * L // 4
+        body = jnp.where(is_raw[:, None], raw, stream[:, :n_body])
+        body = jnp.pad(body, ((0, 0), (0, R_cap * LANES - n_body)))
+        stored = jnp.where(is_raw, n_raw, n_comp)
+        n_sealed = -(-stored // 4)                           # stored u32 words
+    sealed, p, q = seal_words_pallas(
+        body.reshape(B, R_cap, LANES), keys, nonces, n_sealed.reshape(B, 1),
+        q_coef, n_shards=n_shards, parity=parity, interpret=interpret,
+    )
+    return sealed, n_words[:, None], p, q

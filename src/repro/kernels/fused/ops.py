@@ -1,4 +1,4 @@
-"""Public wrappers for the one-launch entropy+seal kernel: batching,
+"""Public wrappers for the fused entropy+seal write program: batching,
 padding, dispatch, manifest reconstruction.
 
 ``entropy_seal_stripes`` takes a list of stripes (each a list of ragged
@@ -6,7 +6,8 @@ int8 shard payloads) plus per-stripe session material and returns, per
 stripe, the exact ``(SealedStripe, entropy_metas)`` pair the chained
 ``entropy.encode_payloads`` -> ``seal.seal_stripe`` path would have
 produced — every stored byte, parity word, manifest dict and row count
-bit-identical — from ONE kernel launch per homogeneous batch.
+bit-identical — from ONE device dispatch per homogeneous batch (three
+Pallas kernels inside, see ``entropy_seal.py``).
 
 Batching: stripes are grouped by (shard count, padded lane rows); each
 group launches once with K stripes on the batch axis, so the per-launch
@@ -22,7 +23,7 @@ slice is exact.
 
 ``core_fn`` overrides the fused launch itself — it is called with the
 same arrays plus the launch's static config as keyword arguments
-(``n_shards``/``parity``/``use_pallas``/``interpret``/``division``, since
+(``n_shards``/``parity``/``use_pallas``/``interpret``, since
 ``n_shards`` varies per batch group); the sharded path
 (``repro.distributed.archival``) passes a shard_map'd wrapper, exactly
 like the ``core_fn`` seams of the entropy and seal ops.
@@ -49,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.archival.raid import gf_pow_gen
-from repro.kernels import as_payload_list, use_interpret
+from repro.kernels import as_payload_list, stack_rows, use_interpret
 from repro.obs import OBS, names as obs_names
 from repro.kernels.entropy.ops import HEADER_BYTES, MAX_ROWS, rows_for
 from repro.kernels.entropy.rans import N_LANES, STREAM_VERSION
@@ -97,20 +98,18 @@ class PendingSeal(NamedTuple):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_shards", "parity", "use_pallas", "interpret",
-                     "division"),
+    static_argnames=("n_shards", "parity", "use_pallas", "interpret"),
 )
 def _fused_core(codes, n_valid, keys, nonces, q_coef, *, n_shards: int,
-                parity: str, use_pallas: bool, interpret: bool,
-                division: str):
+                parity: str, use_pallas: bool, interpret: bool):
     if use_pallas:
         return entropy_seal_pallas(
             codes, n_valid, keys, nonces, q_coef, n_shards=n_shards,
-            parity=parity, division=division, interpret=interpret,
+            parity=parity, interpret=interpret,
         )
     return _ref.entropy_seal_ref(
         codes, n_valid, keys, nonces, q_coef, n_shards=n_shards,
-        parity=parity, division=division,
+        parity=parity,
     )
 
 
@@ -123,7 +122,6 @@ def entropy_seal_stripes_dispatch(
     use_pallas: bool = True,
     interpret: Optional[bool] = None,
     pad_rows=None,
-    division: Optional[str] = None,
     core_fn=None,
 ) -> PendingSeal:
     """Host prep + async launch for a batch of stripes — NO device sync.
@@ -142,10 +140,6 @@ def entropy_seal_stripes_dispatch(
             f"{len(nonces)} nonces"
         )
     interp = use_interpret(interpret)
-    if division is None:
-        # same pick as entropy ops._encode_core: SIMD mulhi reciprocal on
-        # interpret/CPU, repaired-f32 reciprocal on real TPU — identical bits
-        division = "reciprocal" if interp else "rcp32"
     n_stripes = len(stripes)
     if isinstance(pad_rows, (list, tuple)):
         if len(pad_rows) != n_stripes:
@@ -182,12 +176,7 @@ def entropy_seal_stripes_dispatch(
         OBS.count(obs_names.FUSED_STRIPES, len(idxs))
         flats = [p for i in idxs for p in plists[i]]
         n_raw = [int(f.shape[0]) for f in flats]
-        codes = jnp.stack(
-            [
-                jnp.pad(f, (0, T * N_LANES - n)).reshape(T, N_LANES)
-                for f, n in zip(flats, n_raw)
-            ]
-        )
+        codes = stack_rows(flats, T, N_LANES, np.int8)
         n_valid = jnp.asarray(n_raw, jnp.int32).reshape(-1, 1)
         keys_a = jnp.concatenate(
             [jnp.asarray(keys[i], jnp.uint32).reshape(S, 8) for i in idxs]
@@ -201,7 +190,6 @@ def entropy_seal_stripes_dispatch(
         sealed, n_words_rans, p, q = fn(
             codes, n_valid, keys_a, nonces_a, q_coef, n_shards=S,
             parity=parity, use_pallas=use_pallas, interpret=interp,
-            division=division,
         )
         out_groups.append(
             _PendingGroup(idxs, S, T, n_raw, sealed, n_words_rans, p, q)
@@ -261,10 +249,9 @@ def entropy_seal_stripes(
     use_pallas: bool = True,
     interpret: Optional[bool] = None,
     pad_rows=None,
-    division: Optional[str] = None,
     core_fn=None,
 ) -> List[Tuple[SealedStripe, List[Dict]]]:
-    """Fused one-launch archival for a batch of stripes.
+    """Fused archival for a batch of stripes (one dispatch per group).
 
     stripes: per-stripe payload lists (ragged int8, or (S, N) arrays);
     keys / nonces: per-stripe (S, 8) / (S, 3) uint32 session material;
@@ -282,8 +269,7 @@ def entropy_seal_stripes(
     return entropy_seal_stripes_finalize(
         entropy_seal_stripes_dispatch(
             stripes, keys, nonces, parity=parity, use_pallas=use_pallas,
-            interpret=interpret, pad_rows=pad_rows, division=division,
-            core_fn=core_fn,
+            interpret=interpret, pad_rows=pad_rows, core_fn=core_fn,
         )
     )
 
@@ -291,12 +277,10 @@ def entropy_seal_stripes(
 def entropy_seal_stripe(
     payloads, keys, nonces, *, parity: str = "raid6",
     use_pallas: bool = True, interpret: Optional[bool] = None,
-    pad_rows: Optional[int] = None, division: Optional[str] = None,
-    core_fn=None,
+    pad_rows: Optional[int] = None, core_fn=None,
 ) -> Tuple[SealedStripe, List[Dict]]:
     """Single-stripe convenience twin of ``entropy_seal_stripes``."""
     return entropy_seal_stripes(
         [payloads], [keys], [nonces], parity=parity, use_pallas=use_pallas,
-        interpret=interpret, pad_rows=[pad_rows], division=division,
-        core_fn=core_fn,
+        interpret=interpret, pad_rows=[pad_rows], core_fn=core_fn,
     )[0]

@@ -1,17 +1,16 @@
-"""Staged pure-jnp oracle for the one-launch entropy+seal kernel.
+"""Staged pure-jnp oracle for the fused entropy+seal write program.
 
 The pre-fusion pipeline kept as the bit-exact reference and the
 ``use_pallas=False`` fallback: the entropy stage runs the scan-based rANS
 oracle (``kernels/entropy/ref.py`` — an independent schedule from the
-kernel's fori-loop body), the pack runs the shared rank-select gather (the
-pack was host-side shared code in the chained path too, never
-oracle-duplicated), and the seal stages run the staged seal reference
+kernel's row loop) and its sort-based stream compaction, the header
+is serialized byte by byte, and the seal stages run the staged seal reference
 (``kernels/seal/ref.py`` — per-shard ``chacha20_block`` keystream and the
 log/antilog-table GF(256) parity, both independent implementations of the
 kernel's plane-batched ChaCha and SWAR GF multiply).
 
 Each tuple entry below is one full-payload HBM round-trip of the staged
-pipeline; the fused kernel does all of them in one launch per stripe batch.
+pipeline; the fused program does them in three kernels per stripe batch.
 """
 
 from __future__ import annotations
@@ -20,11 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.entropy import ref as eref
-from repro.kernels.entropy.ops import (
-    HEADER_BYTES,
-    _pack_bytes_impl,
-    _pack_rank_impl,
-)
+from repro.kernels.entropy.ops import HEADER_BYTES
 from repro.kernels.fused.entropy_seal import seal_rows_cap, stream_word_cap
 from repro.kernels.seal import ref as sref
 from repro.kernels.seal.seal import ROW_BYTES
@@ -42,6 +37,36 @@ STAGED_PASSES = (
 N_STAGED_PASSES = len(STAGED_PASSES)
 
 
+def _u16_to_u8(w: jax.Array) -> jax.Array:
+    """(..., n) uint16 -> (..., 2n) uint8, little-endian."""
+    lo = (w & jnp.uint16(0xFF)).astype(jnp.uint8)
+    hi = (w >> jnp.uint16(8)).astype(jnp.uint8)
+    return jnp.stack([lo, hi], axis=-1).reshape(*w.shape[:-1], -1)
+
+
+def _u32_to_u8(w: jax.Array) -> jax.Array:
+    """(..., n) uint32 -> (..., 4n) uint8, little-endian."""
+    parts = [
+        ((w >> jnp.uint32(8 * k)) & jnp.uint32(0xFF)).astype(jnp.uint8)
+        for k in range(4)
+    ]
+    return jnp.stack(parts, axis=-1).reshape(*w.shape[:-1], -1)
+
+
+def _serialize_streams(words, lane_lens, freq, states):
+    """Header + word area of B v1 streams -> (B, HEADER + 2 * cap) uint8,
+    byte by byte (the kernel path packs u32 words directly)."""
+    header = jnp.concatenate(
+        [
+            _u16_to_u8(freq.astype(jnp.uint16)),
+            _u32_to_u8(lane_lens.astype(jnp.uint32)),
+            _u32_to_u8(states),
+        ],
+        axis=1,
+    )
+    return jnp.concatenate([header, _u16_to_u8(words)], axis=1)
+
+
 def entropy_seal_ref(
     codes, n_valid, keys, nonces, q_coef, *,
     n_shards: int, parity: str = "raid6", division: str = "divide",
@@ -55,8 +80,10 @@ def entropy_seal_ref(
     words, mask, freq, states = eref.rans_encode_ref(
         codes, n_valid, division=division
     )
-    src, n_words, lane_lens = _pack_rank_impl(mask, cap=stream_word_cap(T))
-    stream_u8 = _pack_bytes_impl(words, src, n_words, lane_lens, freq, states)
+    comp, n_words, lane_lens = eref.compact_ref(
+        words, mask, stream_word_cap(T)
+    )
+    stream_u8 = _serialize_streams(comp, lane_lens, freq, states)
 
     # raw-skip select + pad to the sealed-rows capacity
     n_raw = n_valid.reshape(B)

@@ -10,14 +10,19 @@ same P/Q parity, zero-padded tails.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.archival.raid import gf_pow_gen
-from repro.kernels import as_payload_list, use_interpret
+from repro.kernels import (
+    as_payload_list,
+    host_prefixes,
+    stack_rows,
+    use_interpret,
+)
 from repro.kernels.seal import ref as _ref
 from repro.kernels.seal.seal import (
     LANES,
@@ -47,6 +52,16 @@ class SealedStripe(NamedTuple):
     def body(self, s: int) -> jax.Array:
         """Exact-length flat uint32 sealed body of shard s."""
         return self.sealed[s].reshape(-1)[: self.n_words[s]]
+
+    def bodies(self) -> List[jax.Array]:
+        """Exact-length flat bodies of every shard, as device arrays.
+
+        Sliced on the host from ONE fetch of the stripe (the bodies are on
+        their way to the journal anyway): a device slice per ragged length
+        would compile a program per GOP size, which on a TPU costs more than
+        the whole seal."""
+        host = np.asarray(self.sealed).reshape(self.sealed.shape[0], -1)
+        return [jax.device_put(host[s, :n]) for s, n in enumerate(self.n_words)]
 
     @property
     def pad_words(self) -> int:
@@ -89,11 +104,7 @@ def _stack_padded(
                 f"covering the largest shard ({R} rows)"
             )
         R = pad_rows
-    rows = [
-        jnp.pad(f, (0, R * ROW_BYTES - f.shape[0])).reshape(R, ROW_BYTES)
-        for f in flats
-    ]
-    return jnp.stack(rows), n_words, n_i8
+    return stack_rows(flats, R, ROW_BYTES, np.int8), n_words, n_i8
 
 
 def _meta_arrays(
@@ -170,7 +181,7 @@ def unseal_stripe(stripe: SealedStripe, keys, nonces, *,
                   parity: str = "raid6", use_pallas: bool = True,
                   interpret: Optional[bool] = None,
                   shard_ids: Optional[Sequence[int]] = None):
-    """Fused decode: returns (payload list, P, Q) with parity recomputed
+    """Fused decode: returns (host payload list, P, Q) with parity recomputed
     from the stored bodies (compare against the seal-time parity to verify
     stripe integrity before trusting the decode).
 
@@ -186,10 +197,7 @@ def unseal_stripe(stripe: SealedStripe, keys, nonces, *,
         stripe.sealed, *meta, parity=parity, use_pallas=use_pallas,
         interpret=use_interpret(interpret),
     )
-    flats = [
-        codes[s].reshape(-1)[: stripe.n_i8[s]] for s in range(codes.shape[0])
-    ]
-    return flats, p, q
+    return host_prefixes(codes, stripe.n_i8), p, q
 
 
 def datapath_traffic(S: int, n_words: int, parity: str = "raid6") -> dict:

@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from repro.models.config import MoECfg
 from repro.models.layers.mlp import init_mlp, mlp_forward
 
-try:  # JAX >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["init_moe", "moe_forward"]
 
 
@@ -145,7 +140,7 @@ def _moe_forward_shard_map(
         aux = E * jnp.sum(me * ce)
         return y, aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(wspecs, P(da, None)),

@@ -51,5 +51,5 @@ STRIPES_RETAINED = "lifecycle.stripes_retained"  # gauge
 LOST_CSDS = "lifecycle.lost_csds"              # gauge
 
 # --------------------------------------------------------------- kernels
-FUSED_LAUNCHES = "kernels.fused_launches"      # counter: one-launch groups
+FUSED_LAUNCHES = "kernels.fused_launches"      # counter: fused dispatch groups
 FUSED_STRIPES = "kernels.fused_stripes"        # counter: stripes batched

@@ -51,6 +51,7 @@ from repro.core.archival.pipeline import (
     ArchiveConfig,
     StripeArchive,
     encode_gop_payload,
+    restore_stripe_payloads,
     stripe_manifests,
 )
 from repro.core.archival.scrub import StripeScrubber, retire_stripes
@@ -375,6 +376,17 @@ class ArchiveIngest:
         self.metrics.add(obs_names.RETR_FULL_BYTES, plan.bytes_full_restore)
         self.metrics.add(obs_names.RETR_SKIPPED, plan.skipped)
         return plan
+
+    def restore(self, s, stripe_id: str, shards=None):
+        """Read a retained stripe back: unseal + entropy-decode the shard
+        subset a plan names (``plan.shards_by_stripe[stripe_id]``; None =
+        every shard, parity-verified) with the RLWE secret ``s``.  Lost
+        shards are rebuilt from parity on the way.  Returns (codec
+        payloads, their blocks) in ``shards`` order."""
+        return restore_stripe_payloads(
+            s, self._stripes[stripe_id], self.cfg.archive, shards=shards,
+            manifests=self._manifests[stripe_id],
+        )
 
     # ------------------------------------------------------ durability tier
     def scrub_round(self, budget_bytes: int):

@@ -1,10 +1,10 @@
 """Fault-tolerant checkpointing through the Salient Store archival pipeline.
 
 Checkpoints are archival data: each save is chunked into S logical storage
-shards (stripe tiles) and pushed through the SAME one-launch archival
-kernel as the video archive (``repro.kernels.fused``): interleaved-rANS
-entropy coding + stream pack + ChaCha20 + XOR + RAID-5 P / RAID-6 Q in a
-single launch over the stripe (``codec_name="zstd"``/``"zlib"`` keeps the
+shards (stripe tiles) and pushed through the SAME archival write program
+as the video archive (``repro.kernels.fused``): interleaved-rANS entropy
+coding + stream pack + ChaCha20 + XOR + RAID-5 P / RAID-6 Q in one device
+program over the stripe (``codec_name="zstd"``/``"zlib"`` keeps the
 host codec + chained ``repro.kernels.seal`` as a fallback).  With a ``seal_key``
 the per-shard ChaCha session keys are R-LWE-KEM-encapsulated (true
 encryption, secret needed to restore); without one they are stored in the
@@ -144,8 +144,8 @@ def save_checkpoint(
 
     if codec_name == "rans":
         # chunk the RAW payload into S stripe tiles; entropy + seal run as
-        # ONE on-device launch (repro.kernels.fused) — the checkpoint bytes
-        # never visit a host codec and the packed streams never visit HBM.
+        # one on-device program (repro.kernels.fused) — the checkpoint
+        # bytes never visit a host codec.
         # Big states grow the shard count so each tile stays inside the
         # coder's per-shard bound (entropy_ops.MAX_ROWS rows of 128 lanes)
         # instead of failing the encode launch.

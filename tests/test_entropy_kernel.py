@@ -138,53 +138,53 @@ def test_stream_is_self_contained():
 
 def test_division_strategies_bit_identical():
     """All three per-symbol division strategies — hardware udiv, the
-    error-repaired f32 reciprocal (TPU default; Mosaic has no integer
-    divide), and the Granlund-Montgomery mulhi — must produce identical
-    streams bit-for-bit."""
+    error-repaired f32 reciprocal (what the Pallas kernel runs; Mosaic has
+    no integer divide), and the Granlund-Montgomery mulhi — must produce
+    identical streams bit-for-bit, in the oracle and in the kernel."""
     payloads = [_latents(3, 9000), _latents(4, 100)]
-    outs = {
-        d: eops.encode_payloads(payloads, division=d)
-        for d in ("divide", "rcp32", "reciprocal")
-    }
-    ref_c, ref_m = outs["divide"]
-    for d, (c, m) in outs.items():
+    ref_c, ref_m = eops.encode_payloads(payloads)
+    for d in ("divide", "rcp32", "reciprocal"):
+        c, m = eops.encode_payloads(payloads, use_pallas=False, division=d)
         assert m == ref_m, d
         for a, b in zip(c, ref_c):
             assert _eq(a, b), d
 
 
-def test_row_and_tile_schedules_bit_identical():
-    """The loop schedule (rows per trip: 1 on CPU interpret, the (8, 128)
-    sublane tile on TPU) is pure scheduling — outputs must be identical."""
-    from repro.kernels.entropy.rans import (
-        N_GROUPS,
-        rans_decode_pallas,
-        rans_encode_pallas,
-    )
+def test_row_and_tile_schedules_bit_identical(monkeypatch):
+    """The row-tile grid schedule (rows per grid step: a whole bucket in
+    one step, or 8-row tiles that carry the lane states, and the decode's
+    stream window, from step to step) is pure scheduling — outputs must be
+    identical."""
+    from repro.kernels.entropy import rans
 
     n = 5000
     T = eops.rows_for(n)
     flat = _latents(9, n)
     codes = jnp.stack([jnp.pad(flat, (0, T * N_LANES - n)).reshape(T, N_LANES)])
     nv = jnp.asarray([[n]], jnp.int32)
-    outs = [
-        rans_encode_pallas(codes, nv, rows_per_step=r, interpret=True)
-        for r in (1, N_GROUPS)
-    ]
-    for a, b in zip(*outs):
-        assert _eq(a, b)
-    # decode twin: both schedules reproduce the payload from the packed
-    # version-1 stream
     comp, metas = eops.encode_payloads([flat])
     stream, freq, states = eops._parse_streams(
         jnp.stack([jnp.pad(jnp.asarray(comp[0]).astype(jnp.uint8),
                            (0, (metas[0]["n_comp"] % 2)))])
     )
-    for r in (1, N_GROUPS):
-        got = rans_decode_pallas(
-            stream, freq, states, nv, rows=T, rows_per_step=r, interpret=True
+
+    def run():
+        enc = rans.rans_encode_pallas(codes, nv, interpret=True)
+        dec = rans.rans_decode_pallas(
+            stream, freq, states, nv, rows=T, interpret=True
         )
-        assert _eq(got[0].reshape(-1)[:n], flat)
+        return enc, dec
+
+    (enc_a, dec_a) = run()
+    monkeypatch.setattr(rans, "_TILE_ELEMS", 8 * N_LANES)
+    monkeypatch.setattr(rans, "_DEC_TILE", 8)
+    (enc_b, dec_b) = run()
+    for a, b in zip(enc_a, enc_b):
+        assert _eq(a, b)
+    # decode twin: both schedules reproduce the payload from the packed
+    # version-1 stream
+    for dec in (dec_a, dec_b):
+        assert _eq(dec[0].reshape(-1)[:n], flat)
 
 
 def _bucket_stripe(T, seed):
@@ -225,25 +225,22 @@ def test_two_phase_bit_identity_every_bucket(T):
 
 
 def test_two_phase_histogram_impls_bit_identical():
-    """Both exact histogram strategies (SWAR popcount sweep / one-hot
-    matmul) feed the two-phase schedule identical tables — streams must
-    not differ by a bit."""
-    from repro.kernels.entropy.rans import rans_encode_pallas
+    """The MXU histogram kernel (one-hot nibble matmuls over row tiles)
+    counts exactly what the jnp one-hot oracle counts, padding correction
+    included — so both feed the coder identical tables."""
+    from repro.kernels.entropy.rans import _histogram, byte_histogram
 
     payloads = _bucket_stripe(32, seed=77)
-    outs = {
-        h: eops.encode_payloads(
-            payloads,
-            core_fn=lambda c, nv, h=h: rans_encode_pallas(
-                c, nv, histogram=h, interpret=True
-            ),
-        )
-        for h in ("swar", "dot")
-    }
-    (c_s, m_s), (c_d, m_d) = outs["swar"], outs["dot"]
-    assert m_s == m_d
-    for a, b in zip(c_s, c_d):
-        assert _eq(a, b)
+    T = 32
+    codes = jnp.stack(
+        [jnp.pad(p, (0, T * N_LANES - p.shape[0])).reshape(T, N_LANES)
+         for p in payloads]
+    )
+    nv = jnp.asarray([p.shape[0] for p in payloads], jnp.int32)
+    got = byte_histogram(codes, nv.reshape(-1, 1), interpret=True)
+    want = jax.vmap(_histogram)((codes.astype(jnp.int32) & 0xFF), nv)
+    assert _eq(got, want)
+    assert _eq(got.sum(axis=1), nv)
 
 
 @pytest.mark.parametrize("D", [1, 2, 4, 8])

@@ -14,7 +14,9 @@ import jax.numpy as jnp
 import pytest
 
 from _hyp import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
+from repro.kernels.entropy.ops import MAX_ROWS
 from repro.kernels.entropy.rans import (
+    N_LANES,
     PROB_BITS,
     PROB_SCALE,
     build_enc_tables,
@@ -137,8 +139,14 @@ def test_reciprocal_exact_adversarial(f_val):
 
 # ------------------------------------------------------ hypothesis sweeps
 @settings(max_examples=200, deadline=None)
-@given(st.lists(st.integers(0, 1 << 26), min_size=256, max_size=256))
+@given(
+    st.lists(
+        st.integers(0, MAX_ROWS * N_LANES // 256), min_size=256, max_size=256
+    )
+)
 def test_freq_table_invariants_property(counts):
+    # totals a shard can reach: at most MAX_ROWS * 128 bytes (2^24), well
+    # inside build_freq_table's total < 2^31 precondition
     _check_invariants(np.asarray(counts, np.int64))
 
 

@@ -153,9 +153,13 @@ def test_batched_stripes_match_per_stripe():
         _assert_stripes_equal(got, fops.entropy_seal_stripe(p, k, n))
 
 
-def test_grid_schedule_bit_identical_to_fat_block():
-    """The two multi-stripe schedules (one fat block vs stripes on the
-    launch grid axis) are pure scheduling: identical outputs."""
+def test_grid_schedule_bit_identical_to_fat_block(monkeypatch):
+    """The row-tile grid schedules of the write path (whole-bucket blocks
+    vs minimal 8-row tiles in the coder and the seal) are pure
+    scheduling: identical outputs."""
+    from repro.kernels.entropy import rans
+    from repro.kernels.seal import seal as seal_kernel
+
     S, K = 2, 3
     flats = [p for i in range(K) for p in _payloads(30 + i, [2500, 2501])]
     n_raw = [int(f.shape[0]) for f in flats]
@@ -173,8 +177,11 @@ def test_grid_schedule_bit_identical_to_fat_block():
         entropy_seal_pallas, codes, n_valid, keys, nonces, q_coef,
         n_shards=S, parity="raid6", interpret=True,
     )
-    fat = run(grid_stripes=False)
-    grid = run(grid_stripes=True)
+    fat = run()
+    monkeypatch.setattr(rans, "_TILE_ELEMS", 8 * 8 * N_LANES)
+    monkeypatch.setattr(rans, "_HIST_TILE", 8)
+    monkeypatch.setattr(seal_kernel, "_MAX_TILE", 8)
+    grid = run()
     for a, b in zip(fat, grid):
         assert _eq(a, b)
 

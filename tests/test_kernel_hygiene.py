@@ -154,9 +154,17 @@ def _induction_indexed_fori_loops(source: str):
     defs = {
         n.name: n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)
     }
+    # inside a Pallas kernel body (``_*_kernel``) a fori_loop over VMEM
+    # ref rows is the native sequential form; the rule is for XLA code
+    in_kernel = {
+        id(n)
+        for k in ast.walk(tree)
+        if isinstance(k, ast.FunctionDef) and k.name.endswith("_kernel")
+        for n in ast.walk(k)
+    }
     src_lines = source.splitlines()
     for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
+        if not isinstance(node, ast.Call) or id(node) in in_kernel:
             continue
         fn = node.func
         fn_name = fn.attr if isinstance(fn, ast.Attribute) else (
@@ -191,10 +199,10 @@ def _induction_indexed_fori_loops(source: str):
 
 @pytest.mark.parametrize("path", _entropy_sources(), ids=os.path.basename)
 def test_no_induction_indexed_fori_loop_in_entropy(path):
-    """PR 9 removed the per-row ``fori_loop`` carry chain from the entropy
-    encode (the two-phase schedule computes the full emission schedule as
-    batched tensor ops and compacts in one pass); this keeps the
-    serializing construct from returning to the coder column."""
+    """No per-row ``fori_loop`` carry chain in the XLA-level entropy code
+    (ops, oracle): there it serializes a dynamic-update chain on every
+    backend.  Pallas kernel bodies are exempt — their row loops walk VMEM
+    refs, the sequential form Mosaic lowers natively."""
     with open(path) as f:
         offenders = [
             f"{path}:{line}: {text}"
