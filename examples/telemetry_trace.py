@@ -14,6 +14,10 @@ fused seal -> catalog), serves one budgeted retrieval plan, then dumps:
 The ledger report at the end is the paper's data-movement claim computed
 from edges alone — no counters hand-wired into the pipeline.
 
+Every span also lands in the JAX profiler's trace as a ``TraceMe`` of the
+same name: run this under ``jax.profiler.trace(dir)`` and the spans sit
+beside the device operations in the ``.xplane.pb``, on their clock.
+
 Run:  PYTHONPATH=src python examples/telemetry_trace.py
 """
 

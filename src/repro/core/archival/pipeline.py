@@ -552,15 +552,16 @@ def seal_payload_stripes_dispatch(
             for f, m, k, pr in zip(stripes, manifests, keys, pr_list)
         ]
         return PendingStripeSeal(None, None, archives, [], [])
-    mats = [
-        [
-            encapsulate_session(pub, jax.random.fold_in(k, s), cfg.rlwe)
-            for s in range(len(f))
+    with OBS.span("ingest.kem", stripes=n):
+        mats = [
+            [
+                encapsulate_session(pub, jax.random.fold_in(k, s), cfg.rlwe)
+                for s in range(len(f))
+            ]
+            for k, f in zip(keys, stripes)
         ]
-        for k, f in zip(keys, stripes)
-    ]
-    keys_a = [jnp.stack([m.session for m in ms]) for ms in mats]
-    nonces_a = [jnp.stack([m.nonce for m in ms]) for ms in mats]
+        keys_a = [jnp.stack([m.session for m in ms]) for ms in mats]
+        nonces_a = [jnp.stack([m.nonce for m in ms]) for ms in mats]
     with OBS.span(
         "archive.seal", stripes=n, shards=len(stripes[0]),
         codec=cfg.codec_name, parity=cfg.parity,

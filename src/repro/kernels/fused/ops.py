@@ -174,23 +174,25 @@ def entropy_seal_stripes_dispatch(
         # let the seal span report its exact launch amortization
         OBS.count(obs_names.FUSED_LAUNCHES)
         OBS.count(obs_names.FUSED_STRIPES, len(idxs))
-        flats = [p for i in idxs for p in plists[i]]
-        n_raw = [int(f.shape[0]) for f in flats]
-        codes = stack_rows(flats, T, N_LANES, np.int8)
-        n_valid = jnp.asarray(n_raw, jnp.int32).reshape(-1, 1)
-        keys_a = jnp.concatenate(
-            [jnp.asarray(keys[i], jnp.uint32).reshape(S, 8) for i in idxs]
-        )
-        nonces_a = jnp.concatenate(
-            [jnp.asarray(nonces[i], jnp.uint32).reshape(S, 3) for i in idxs]
-        )
-        coefs = [gf_pow_gen(s) for s in range(S)]
-        q_coef = jnp.asarray(coefs * len(idxs), jnp.uint32).reshape(-1, 1)
-        fn = core_fn or _fused_core
-        sealed, n_words_rans, p, q = fn(
-            codes, n_valid, keys_a, nonces_a, q_coef, n_shards=S,
-            parity=parity, use_pallas=use_pallas, interpret=interp,
-        )
+        # host staging of the launch's inputs and the launch call itself
+        with OBS.span("kernels.stage", rows=T, stripes=len(idxs)):
+            flats = [p for i in idxs for p in plists[i]]
+            n_raw = [int(f.shape[0]) for f in flats]
+            codes = stack_rows(flats, T, N_LANES, np.int8)
+            n_valid = jnp.asarray(n_raw, jnp.int32).reshape(-1, 1)
+            keys_a = jnp.concatenate([
+                jnp.asarray(keys[i], jnp.uint32).reshape(S, 8) for i in idxs
+            ])
+            nonces_a = jnp.concatenate([
+                jnp.asarray(nonces[i], jnp.uint32).reshape(S, 3) for i in idxs
+            ])
+            coefs = [gf_pow_gen(s) for s in range(S)]
+            q_coef = jnp.asarray(coefs * len(idxs), jnp.uint32).reshape(-1, 1)
+            fn = core_fn or _fused_core
+            sealed, n_words_rans, p, q = fn(
+                codes, n_valid, keys_a, nonces_a, q_coef, n_shards=S,
+                parity=parity, use_pallas=use_pallas, interpret=interp,
+            )
         out_groups.append(
             _PendingGroup(idxs, S, T, n_raw, sealed, n_words_rans, p, q)
         )
@@ -207,7 +209,10 @@ def entropy_seal_stripes_finalize(
     pr_list = pending.pr_list
     for g in pending.groups:
         S, T = g.S, g.T
-        nw_host = [int(w) for w in np.asarray(g.n_words_rans).reshape(-1)]
+        # the host waits here for the launch to finish
+        with OBS.span("kernels.fetch", stripes=len(g.idxs)):
+            nw_host = [int(w) for w in
+                       np.asarray(g.n_words_rans).reshape(-1)]
         for j, i in enumerate(g.idxs):
             off = j * S
             metas, stored_words, stored_len = [], [], []

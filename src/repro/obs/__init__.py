@@ -5,7 +5,10 @@ stripe lifecycle reports into:
 
 * ``OBS.span("archive.seal", stripes=4)`` — nested spans with monotonic
   durations and structured attrs (stripe ids, shard counts, codec names,
-  Pallas launch counts).  Exports as JSONL or a Chrome/Perfetto trace.
+  Pallas launch counts).  Exports as JSONL or a Chrome/Perfetto trace,
+  and each span also lands in the JAX profiler's trace as a ``TraceMe``
+  of the same name, on the device operations' clock; the tracer's public
+  ``epoch_ns`` maps its events onto any clock a reader has an anchor for.
 * ``OBS.metrics`` — counters / gauges / fixed-bucket histograms (p50/p95/
   p99 without stored samples).  Canonical names in :mod:`repro.obs.names`.
 * ``OBS.ledger`` — every byte crossing a lifecycle boundary attributed to
@@ -16,8 +19,8 @@ Zero overhead when disabled — the contract every hot path relies on:
 ``OBS`` starts disabled; ``span()`` then returns the shared ``NULL_SPAN``
 and ``count``/``flow``/``observe``/``gauge`` return after a single
 attribute test.  No event, no allocation beyond the argument tuple, no
-timestamps.  The ``obs_overhead`` bench gates the enabled cost at <= 3%
-of ``seal_payload_stripe``; disabled cost is one branch.
+timestamps, no ``TraceMe``.  The ``obs_overhead`` bench gates the enabled
+cost at <= 3% of ``seal_payload_stripe``; disabled cost is one branch.
 
 Instrumented call sites follow one pattern::
 

@@ -20,6 +20,11 @@ ING_GOP_LATENCY_US = "ingest.gop_to_commit_us"  # histogram: submit->sealed
 ING_QUEUE_DEPTH = "ingest.queue_depth"         # gauge: frontend queued bytes
 ING_SHED_BYTES = "ingest.shed_bytes"           # counter: admission sheds
 ING_SHED_GOPS = "ingest.shed_gops"             # counter: GOPs shed
+# counters of OBS alone, added per seal batch while telemetry is on: the
+# microseconds each GOP waited from its offer stamp to its stripe's
+# dispatch, and the GOPs with a stamp they cover
+ING_DISPATCH_WAIT_US = "ingest.dispatch_wait_us"
+ING_DISPATCHED_GOPS = "ingest.dispatched_gops"
 
 # ------------------------------------------------------------- retrieval
 RETR_PLANS = "retrieval.plans_served"          # counter

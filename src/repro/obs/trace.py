@@ -4,7 +4,16 @@ A :class:`Span` is a context manager; entering pushes it on the tracer's
 stack (so spans opened inside it become its children), exiting records a
 finished-span event ``{id, parent, name, ts_ns, dur_ns, attrs}`` with
 timestamps from ``time.perf_counter_ns`` (monotonic — wall-clock steps
-never produce negative durations) relative to the tracer's epoch.
+never produce negative durations) relative to the tracer's epoch,
+``tracer.epoch_ns`` (an absolute ``perf_counter_ns``): a reader with one
+anchor on another clock puts any event on that clock.
+
+Every span also opens a profiler ``TraceMe`` of the same name
+(``jax.profiler.TraceAnnotation``, imported only here, on the enabled
+path).  Inside a profiler session the program's spans then sit on the
+host plane of the ``.xplane.pb``, beside the device operations and on
+their clock, so Perfetto or TensorBoard lays them against the device's
+idle gaps; outside one a ``TraceMe`` records nothing.
 
 Structured attributes ride on the span: pass them at creation
 (``tracer.span("archive.seal", stripes=4, codec="rans")``) or attach
@@ -45,7 +54,8 @@ NULL_SPAN = NullSpan()
 
 
 class Span:
-    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0",
+                 "_trace_me")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict):
         self._tracer = tracer
@@ -54,6 +64,7 @@ class Span:
         self.span_id = 0
         self.parent_id = 0
         self._t0 = 0
+        self._trace_me = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (launch counts, sizes)."""
@@ -66,12 +77,17 @@ class Span:
         tr._next_id += 1
         self.parent_id = tr._stack[-1] if tr._stack else 0
         tr._stack.append(self.span_id)
+        from jax.profiler import TraceAnnotation
+
+        self._trace_me = TraceAnnotation(self.name)
+        self._trace_me.__enter__()
         self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         t1 = tr._clock()
+        self._trace_me.__exit__(*exc)
         if tr._stack and tr._stack[-1] == self.span_id:
             tr._stack.pop()
         tr._finish(self, self._t0, t1)
@@ -88,7 +104,7 @@ class Tracer:
         self.dropped = 0
         self._stack: List[int] = []
         self._next_id = 1
-        self._epoch = clock()
+        self.epoch_ns = clock()
 
     def span(self, name: str, **attrs) -> Span:
         return Span(self, name, attrs)
@@ -102,7 +118,7 @@ class Tracer:
                 "id": span.span_id,
                 "parent": span.parent_id,
                 "name": span.name,
-                "ts_ns": t0 - self._epoch,
+                "ts_ns": t0 - self.epoch_ns,
                 "dur_ns": t1 - t0,
                 "attrs": span.attrs,
             }
@@ -113,4 +129,4 @@ class Tracer:
         self.dropped = 0
         self._stack = []
         self._next_id = 1
-        self._epoch = self._clock()
+        self.epoch_ns = self._clock()

@@ -260,6 +260,14 @@ class ArchiveIngest:
             keys.append(jax.random.fold_in(self._key, self._stripe_seq))
             stripe_ids.append(f"ingest_{self._stripe_seq:08d}")
             self._stripe_seq += 1
+        if OBS.enabled:
+            # how long each GOP waited, from its offer to this dispatch
+            now = time.perf_counter_ns()
+            stamps = [(g.meta or {}).get("_t_submit") for cs in ready
+                      for g in cs.gops]
+            waits = [now - t for t in stamps if t is not None]
+            OBS.metrics.add(obs_names.ING_DISPATCH_WAIT_US, sum(waits) / 1e3)
+            OBS.metrics.add(obs_names.ING_DISPATCHED_GOPS, len(waits))
         with OBS.span(
             "ingest.seal", stripes=len(ready),
             codec=self.cfg.archive.codec_name,
@@ -277,43 +285,47 @@ class ArchiveIngest:
         if slot is None:
             return []
         ready, stripe_ids, pending = slot
-        stripes = seal_coalesced_stripes_finalize(pending)
-        t_commit = time.perf_counter_ns()
-        for cs, stripe_id, stripe in zip(ready, stripe_ids, stripes):
-            for b in stripe.blocks:
-                em = b.manifest.get("entropy")
-                if em and em.get("codec") != "none":
-                    self.metrics.add(
-                        obs_names.ING_ENTROPY_RAW, int(em["n_raw"])
+        with OBS.span("ingest.commit", stripes=len(ready)):
+            stripes = seal_coalesced_stripes_finalize(pending)
+            t_commit = time.perf_counter_ns()
+            # the journal commit: each stripe's catalog record, fsynced
+            with OBS.span("ingest.journal", stripes=len(stripes)):
+                for cs, stripe_id, stripe in zip(ready, stripe_ids, stripes):
+                    self.catalog.add_stripe(
+                        stripe_id,
+                        stripe,
+                        gop_descriptors(
+                            cs.gops,
+                            self.catalog.feature_dim or self.cfg.feature_dim,
+                        ),
                     )
-                    self.metrics.add(
-                        obs_names.ING_ENTROPY_COMP, int(em["n_comp"])
-                    )
-            for g in cs.gops:
-                t_sub = (g.meta or {}).get("_t_submit")
-                if t_sub is not None:
-                    self.metrics.observe(
-                        obs_names.ING_GOP_LATENCY_US,
-                        (t_commit - t_sub) / 1e3,
-                    )
-            self.catalog.add_stripe(
-                stripe_id,
-                stripe,
-                gop_descriptors(
-                    cs.gops,
-                    self.catalog.feature_dim or self.cfg.feature_dim,
-                ),
+            for cs, stripe_id, stripe in zip(ready, stripe_ids, stripes):
+                for b in stripe.blocks:
+                    em = b.manifest.get("entropy")
+                    if em and em.get("codec") != "none":
+                        self.metrics.add(
+                            obs_names.ING_ENTROPY_RAW, int(em["n_raw"])
+                        )
+                        self.metrics.add(
+                            obs_names.ING_ENTROPY_COMP, int(em["n_comp"])
+                        )
+                for g in cs.gops:
+                    t_sub = (g.meta or {}).get("_t_submit")
+                    if t_sub is not None:
+                        self.metrics.observe(
+                            obs_names.ING_GOP_LATENCY_US,
+                            (t_commit - t_sub) / 1e3,
+                        )
+                self._stripes[stripe_id] = stripe
+                self._manifests[stripe_id] = stripe_manifests(stripe)
+            self.metrics.set_gauge(obs_names.CAT_GOPS, len(self.catalog))
+            self.metrics.set_gauge(
+                obs_names.CAT_BYTES, self.catalog.bytes_indexed
             )
-            self._stripes[stripe_id] = stripe
-            self._manifests[stripe_id] = stripe_manifests(stripe)
-        self.metrics.set_gauge(obs_names.CAT_GOPS, len(self.catalog))
-        self.metrics.set_gauge(
-            obs_names.CAT_BYTES, self.catalog.bytes_indexed
-        )
-        self.metrics.set_gauge(
-            obs_names.STRIPES_RETAINED, len(self._stripes)
-        )
-        return list(stripes)
+            self.metrics.set_gauge(
+                obs_names.STRIPES_RETAINED, len(self._stripes)
+            )
+            return list(stripes)
 
     def _seal(self, ready) -> List[StripeArchive]:
         # the synchronous entry IS dispatch+commit back-to-back, so the

@@ -20,8 +20,8 @@ module is the edge server's camera-facing front door over it:
   anyway).  A shed is never silent: each one appends a journal record
   (stream id, sequence number, novelty, bytes, reason), lands on the
   ``ingest.shed`` ledger edge (billed at exactly one call site,
-  ``_shed``), and bumps the ``ingest.shed_bytes``/``ingest.shed_gops``
-  counters.
+  ``_shed``), and bumps the tier registry's ``ingest.shed_bytes``/
+  ``ingest.shed_gops`` counters.
 * **Two-slot submit ring** — ``pump()`` moves admitted GOPs into the
   coalescer and walks ready stripes through
   ``_seal_dispatch``/``_seal_commit`` (the split around the fused seal's
@@ -202,7 +202,6 @@ class StreamIngestFrontend:
             st.queue.append(gop)
             self._queued_bytes += nbytes
         self._enforce_budget()
-        OBS.gauge(obs_names.ING_QUEUE_DEPTH, self.queue_bytes)
         self.metrics.set_gauge(obs_names.ING_QUEUE_DEPTH, self.queue_bytes)
         return admitted
 
@@ -246,8 +245,6 @@ class StreamIngestFrontend:
             )
         self._shed_seq += 1
         OBS.flow(EDGE_INGEST_SHED, gop.nbytes)
-        OBS.count(obs_names.ING_SHED_BYTES, gop.nbytes)
-        OBS.count(obs_names.ING_SHED_GOPS)
         self.metrics.add(obs_names.ING_SHED_BYTES, gop.nbytes)
         self.metrics.add(obs_names.ING_SHED_GOPS)
 
@@ -273,16 +270,35 @@ class StreamIngestFrontend:
             queues = next_round
         return ready
 
+    def _admit(self, *, flush: bool = False,
+               now_ns: Optional[int] = None) -> List:
+        """Queued GOPs into the coalescer, then its ready stripes out:
+        those the GOPs filled, plus the deadline-expired buckets (or, with
+        ``flush``, every bucket)."""
+        if not OBS.enabled:
+            return self._admit_ready(flush, now_ns)
+        gops0 = self.metrics.get(obs_names.ING_GOPS)
+        with OBS.span("ingest.admit") as sp:
+            ready = self._admit_ready(flush, now_ns)
+            sp.set(gops=int(self.metrics.get(obs_names.ING_GOPS) - gops0),
+                   stripes=len(ready))
+        return ready
+
+    def _admit_ready(self, flush: bool, now_ns: Optional[int]) -> List:
+        ready = self._admit_to_coalescer()
+        if flush:
+            return ready + self.ingest.coalescer.flush()
+        return ready + self.ingest.coalescer.drain_expired(
+            self.cfg.deadline_us, now_ns=now_ns
+        )
+
     def pump(self, *, now_ns: Optional[int] = None) -> List[StripeArchive]:
         """Advance the machine one turn: admit queued GOPs, deadline-drain
         straggler buckets, and walk ready stripes through the two-slot
         submit ring.  Returns the stripes COMMITTED this turn (the ring
         may still hold one dispatched-but-unfetched slot — ``drain`` it).
         """
-        ready = self._admit_to_coalescer()
-        ready += self.ingest.coalescer.drain_expired(
-            self.cfg.deadline_us, now_ns=now_ns
-        )
+        ready = self._admit(now_ns=now_ns)
         committed: List[StripeArchive] = []
         B = max(1, int(self.cfg.batch_stripes))
         for i in range(0, len(ready), B):
@@ -301,8 +317,7 @@ class StreamIngestFrontend:
     def drain(self) -> List[StripeArchive]:
         """Flush everything: queued GOPs, partial coalescer buckets, and
         the ring's in-flight slot.  The frontend is empty afterwards."""
-        ready = self._admit_to_coalescer()
-        ready += self.ingest.coalescer.flush()
+        ready = self._admit(flush=True)
         committed: List[StripeArchive] = []
         if self._inflight is not None:
             committed += self.ingest._seal_commit(self._inflight)

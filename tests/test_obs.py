@@ -1,11 +1,15 @@
 """Telemetry tier tests: histogram accuracy, snapshot windowing, the
 disabled fast path, byte-ledger conservation across the stripe lifecycle,
-registry-backed engine stats, and the trainer-level acceptance loop
-(Perfetto trace + ledger report whose ratios recompute from edges alone).
+registry-backed engine stats, the trainer-level acceptance loop
+(Perfetto trace + ledger report whose ratios recompute from edges alone),
+spans in the JAX profiler's trace, and the served write path's spans and
+counters.
 """
 
+import glob
 import json
 import os
+import time
 
 import numpy as np
 import jax
@@ -330,3 +334,137 @@ def test_trainer_telemetry_trace_and_ledger(tmp_path):
     with open(paths["jsonl"]) as f:
         kinds = [json.loads(ln)["kind"] for ln in f if ln.strip()]
     assert "span" in kinds and "metrics" in kinds and "ledger" in kinds
+
+
+# ------------------------------------------------ the profiler's trace
+def _host_events(logdir):
+    """Every event on the host planes of the one ``.xplane.pb`` a
+    profiler session wrote under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    return [e for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_enabled_span_is_a_trace_me_on_the_profiler_clock(tmp_path):
+    """An enabled span lands in the profiler's trace under its own name,
+    and the tracer's public epoch maps it onto the trace's clock from one
+    anchor stamped on ``perf_counter_ns``."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.enabled():
+            t_anchor = time.perf_counter_ns()
+            with jax.profiler.TraceAnnotation("test.anchor"):
+                pass
+            with OBS.span("test.traced", stripes=1):
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (ev,) = OBS.tracer.events
+    host = _host_events(str(tmp_path))
+    (anchor,) = [e for e in host if e.name == "test.anchor"]
+    (copy,) = [e for e in host if e.name == "test.traced"]
+    offset = int(anchor.start_ns) - t_anchor
+    start = OBS.tracer.epoch_ns + ev["ts_ns"] + offset
+    assert abs(start - int(copy.start_ns)) < 1_000_000
+    assert abs(ev["dur_ns"] - int(copy.duration_ns)) < 1_000_000
+    assert ev["dur_ns"] >= 5_000_000
+
+
+def test_disabled_span_opens_no_trace_me(tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with OBS.span("test.untraced", stripes=1):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    assert OBS.tracer.events == []
+    assert not [e for e in _host_events(str(tmp_path))
+                if e.name == "test.untraced"]
+
+
+# ------------------------------------------- the served write path's spans
+# span -> the span it must sit under (None: any place)
+WRITE_PATH_SPANS = {
+    "ingest.admit": None,
+    "ingest.seal": None,
+    "ingest.kem": "ingest.seal",
+    "archive.seal": "ingest.seal",
+    "kernels.stage": "ingest.seal",
+    "ingest.commit": None,
+    "kernels.fetch": "ingest.commit",
+    "ingest.journal": "ingest.commit",
+}
+
+
+@pytest.fixture(scope="module")
+def served_backlog(tmp_path_factory):
+    """A tiny backlog through ``StreamIngestFrontend`` with a journal,
+    telemetry on: (the span events, the OBS counters, the committed
+    stripes)."""
+    from repro.core.csd.failure import Journal
+    from repro.serving.engine import ArchiveIngest, IngestConfig
+    from repro.serving.ingest import FrontendConfig, StreamIngestFrontend
+
+    pub, _ = rlwe.keygen(jax.random.PRNGKey(7))
+    journal = Journal(str(tmp_path_factory.mktemp("journal")))
+    ing = ArchiveIngest(None, pub, IngestConfig(), seed=3, journal=journal)
+    fe = StreamIngestFrontend(
+        ing, FrontendConfig(max_stream_gops=64, queue_budget_bytes=1 << 30,
+                            batch_stripes=2, deadline_us=1e15),
+        seed=5, journal=journal)
+    rng = np.random.default_rng(11)
+    committed = []
+    obs.disable()
+    obs.reset()
+    with obs.enabled():
+        for g in range(22):
+            n = int(rng.integers(1024, 3072))
+            fe.offer(g % 4, rng.integers(-6, 7, n).astype(np.int8),
+                     {"spec": [], "n_i8": n}, novelty=float(rng.random()))
+            if g % 6 == 5:
+                committed += fe.pump()
+        committed += fe.pump()
+        committed += fe.drain()
+        events = list(OBS.tracer.events)
+        counters = {k: OBS.metrics.get(k) for k in (
+            obs_names.ING_DISPATCHED_GOPS, obs_names.ING_DISPATCH_WAIT_US)}
+    obs.reset()
+    assert not fe.shed_log
+    return events, counters, committed
+
+
+def test_write_path_spans_nest(served_backlog):
+    events, _, _ = served_backlog
+    by_id = {e["id"]: e for e in events}
+
+    def ancestors(e):
+        while e["parent"]:
+            e = by_id[e["parent"]]
+            yield e["name"]
+
+    names = {e["name"] for e in events}
+    assert set(WRITE_PATH_SPANS) <= names
+    for e in events:
+        under = WRITE_PATH_SPANS.get(e["name"])
+        if under is not None:
+            assert under in set(ancestors(e)), e
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_PATH_SPANS))
+def test_write_path_span_stripes_sum_to_committed(served_backlog, name):
+    events, _, committed = served_backlog
+    assert len(committed) >= 4
+    spans = [e for e in events if e["name"] == name]
+    assert sum(e["attrs"]["stripes"] for e in spans) == len(committed)
+
+
+def test_dispatched_gops_count_the_gops_sealed(served_backlog):
+    _, counters, committed = served_backlog
+    sealed = sum(len(st.blocks) for st in committed)
+    assert sealed == 22
+    assert counters[obs_names.ING_DISPATCHED_GOPS] == sealed
+    assert counters[obs_names.ING_DISPATCH_WAIT_US] > 0
