@@ -161,7 +161,9 @@ from repro.core.codec.layered_codec import (
 from repro.core.crypto import rlwe
 from repro.core.crypto.hybrid import (
     SealedBlock,
+    SessionMaterial,
     encapsulate_session,
+    encapsulate_sessions,
     seal,
     unseal,
 )
@@ -511,6 +513,52 @@ class PendingStripeSeal(NamedTuple):
     manifests: List[List[Dict]]
 
 
+#: sessions per KEM program: a full launch's 4 stripes x 4 data shards,
+#: and a multiple of the 8-row tile ``polymul_fixed`` takes.  Every
+#: dispatch pads its session count up to a multiple of this, so one
+#: program serves them all and none is built after the warm-up.
+KEM_ROWS = 16
+
+
+@functools.partial(jax.jit, static_argnames=("params",))
+def _encapsulate_rows(pub, stripe_keys, shards, params):
+    """One device program for ``KEM_ROWS`` sessions: row v is
+    ``encapsulate_session(pub, fold_in(stripe_keys[v], shards[v]))``.
+    Returns one ``SessionMaterial`` per row, so that nothing is sliced
+    eagerly afterwards."""
+    keys = jax.vmap(jax.random.fold_in)(jnp.stack(stripe_keys), shards)
+    sm = encapsulate_sessions(pub, keys, params)
+    return [SessionMaterial(*(f[v] for f in sm))
+            for v in range(len(stripe_keys))]
+
+
+# a stripe's key (or nonce) rows in one dispatch; an eager ``jnp.stack``
+# of S rows makes S + 1
+_stack = jax.jit(jnp.stack)
+
+
+def _encapsulate_stripes(pub, keys, shard_counts, params):
+    """Every shard's session material, per stripe, shard s of a stripe
+    keyed by ``fold_in(stripe key, s)``.  The V sessions run as
+    ceil(V / KEM_ROWS) launches of one program; a chunk's padding rows
+    repeat its first row and are dropped."""
+    rows = [(k, s) for k, S in zip(keys, shard_counts) for s in range(S)]
+    mats = []
+    for c in range(0, len(rows), KEM_ROWS):
+        chunk = rows[c:c + KEM_ROWS]
+        real = len(chunk)
+        chunk += chunk[:1] * (KEM_ROWS - real)
+        OBS.count(obs_names.KEM_LAUNCHES)
+        OBS.count(obs_names.KEM_SESSIONS, real)
+        OBS.count(obs_names.KEM_PADDED, KEM_ROWS - real)
+        mats += _encapsulate_rows(
+            pub, [k for k, _ in chunk],
+            np.array([s for _, s in chunk], np.uint32), params,
+        )[:real]
+    it = iter(mats)
+    return [[next(it) for _ in range(S)] for S in shard_counts]
+
+
 def seal_payload_stripes_dispatch(
     pub: rlwe.PublicKey,
     stripes: List[List[jax.Array]],
@@ -553,15 +601,11 @@ def seal_payload_stripes_dispatch(
         ]
         return PendingStripeSeal(None, None, archives, [], [])
     with OBS.span("ingest.kem", stripes=n):
-        mats = [
-            [
-                encapsulate_session(pub, jax.random.fold_in(k, s), cfg.rlwe)
-                for s in range(len(f))
-            ]
-            for k, f in zip(keys, stripes)
-        ]
-        keys_a = [jnp.stack([m.session for m in ms]) for ms in mats]
-        nonces_a = [jnp.stack([m.nonce for m in ms]) for ms in mats]
+        mats = _encapsulate_stripes(
+            pub, keys, [len(f) for f in stripes], cfg.rlwe
+        )
+        keys_a = [_stack([m.session for m in ms]) for ms in mats]
+        nonces_a = [_stack([m.nonce for m in ms]) for ms in mats]
     with OBS.span(
         "archive.seal", stripes=n, shards=len(stripes[0]),
         codec=cfg.codec_name, parity=cfg.parity,
@@ -660,10 +704,10 @@ def seal_payload_stripe(
     kernel (``repro.kernels.fused``): codes -> histogram/freq-table ->
     rANS -> v1 pack -> raw-skip -> ChaCha20 XOR-seal -> RAID-P/Q in a
     single Pallas launch, packed streams never materialized in HBM.
-    Per-shard session keys are KEM-encapsulated host-side first (tiny,
-    and the ``fold_in`` order matches the chained path, so archives are
-    bit-identical).  ``fused_fn`` overrides the fused launch (the sharded
-    path passes a shard_map'd wrapper); passing only ``seal_fn`` /
+    Per-shard session keys are KEM-encapsulated first, in one program
+    for the batch (the ``fold_in`` order matches the chained path, so
+    archives are bit-identical).  ``fused_fn`` overrides the fused launch
+    (the sharded path passes a shard_map'd wrapper); passing only ``seal_fn`` /
     ``entropy_fn`` (same signatures as ``seal_ops.seal_stripe`` /
     ``entropy_ops.encode_payloads``) keeps the two-launch chained path —
     which also serves host codecs and stays the decode-side reference.

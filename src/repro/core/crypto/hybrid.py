@@ -22,6 +22,7 @@ __all__ = [
     "SealedBlock",
     "SessionMaterial",
     "encapsulate_session",
+    "encapsulate_sessions",
     "seal",
     "unseal",
     "bytes_to_u32",
@@ -61,6 +62,15 @@ class SessionMaterial(NamedTuple):
     nonce: jax.Array  # (3,) uint32
 
 
+def _split_session_key(key: jax.Array):
+    """A shard key's KEM key and its (3,) uint32 nonce."""
+    k_kem, k_nonce = jax.random.split(key)
+    nonce = jax.random.randint(
+        k_nonce, (3,), 0, jnp.iinfo(jnp.int32).max, dtype=jnp.int32
+    ).astype(jnp.uint32)
+    return k_kem, nonce
+
+
 def encapsulate_session(
     pub: rlwe.PublicKey,
     key: jax.Array,
@@ -68,15 +78,26 @@ def encapsulate_session(
 ) -> SessionMaterial:
     """Fresh session key + nonce under the lattice KEM.
 
-    Split out of ``seal`` so batched paths (the fused stripe kernel in
-    ``repro.kernels.seal``) can run the tiny per-shard KEM host-side and hand
-    all S session keys to one kernel launch for the bulk bytes.
+    Split out of ``seal`` so batched paths can hand all S session keys to
+    one kernel launch for the bulk bytes; the fused stripe seal makes them
+    with ``encapsulate_sessions``.
     """
-    k_kem, k_nonce = jax.random.split(key)
+    k_kem, nonce = _split_session_key(key)
     ct, session = rlwe.kem_encapsulate(pub, k_kem, params)
-    nonce = jax.random.randint(
-        k_nonce, (3,), 0, jnp.iinfo(jnp.int32).max, dtype=jnp.int32
-    ).astype(jnp.uint32)
+    return SessionMaterial(ct.c1, ct.c2, session, nonce)
+
+
+def encapsulate_sessions(
+    pub: rlwe.PublicKey,
+    keys: jax.Array,
+    params: rlwe.RLWEParams = rlwe.RLWEParams(),
+) -> SessionMaterial:
+    """``encapsulate_session`` for each of V stacked keys, bit for bit:
+    fields of V rows (``kem_c1``/``kem_c2`` (V, 1, n), ``session`` (V, 8),
+    ``nonce`` (V, 3)).  Under ``jit`` the V encapsulations are one device
+    program rather than dozens of eager ones each."""
+    k_kem, nonce = jax.vmap(_split_session_key)(keys)
+    ct, session = rlwe.kem_encapsulate_many(pub, k_kem, params)
     return SessionMaterial(ct.c1, ct.c2, session, nonce)
 
 

@@ -35,6 +35,7 @@ __all__ = [
     "encrypt_bits",
     "decrypt_bits",
     "kem_encapsulate",
+    "kem_encapsulate_many",
     "kem_decapsulate",
     "pack_bits_u32",
     "unpack_bits_u32",
@@ -79,20 +80,30 @@ def keygen(key: jax.Array, params: RLWEParams = RLWEParams()):
     return PublicKey(a, b), s
 
 
+def _encrypt_draw(key: jax.Array, rows: int, params: RLWEParams):
+    """The encryption's randomness for ``rows`` messages: r, e1, e2."""
+    n, q, k = params
+    kr, k1, k2 = jax.random.split(key, 3)
+    return tuple(_sample_cbd(kk, (rows, n), k, q) for kk in (kr, k1, k2))
+
+
+def _encrypt_rows(pub: PublicKey, m_bits, r, e1, e2, params: RLWEParams):
+    """C1 = a o r + e1, C2 = b o r + e2 + encode(m), row by row: one
+    ``polymul_fixed`` call for each of ``a`` and ``b`` over all rows."""
+    q = params.q
+    c1 = jnp.mod(polymul_fixed(pub.a, r, q) + e1, q)
+    c2 = jnp.mod(
+        polymul_fixed(pub.b, r, q) + e2 + m_bits.astype(jnp.int32) * (q // 2), q
+    )
+    return Ciphertext(c1, c2)
+
+
 def encrypt_bits(
     pub: PublicKey, m_bits: jax.Array, key: jax.Array, params: RLWEParams = RLWEParams()
 ) -> Ciphertext:
     """Encrypt a batch of bit-vectors. m_bits: (B, n) in {0, 1}."""
-    n, q, k = params
-    B = m_bits.shape[0]
-    kr, k1, k2 = jax.random.split(key, 3)
-    r = _sample_cbd(kr, (B, n), k, q)
-    e1 = _sample_cbd(k1, (B, n), k, q)
-    e2 = _sample_cbd(k2, (B, n), k, q)
-    half_q = q // 2
-    c1 = jnp.mod(polymul_fixed(pub.a, r, q) + e1, q)
-    c2 = jnp.mod(polymul_fixed(pub.b, r, q) + e2 + m_bits.astype(jnp.int32) * half_q, q)
-    return Ciphertext(c1, c2)
+    draw = _encrypt_draw(key, m_bits.shape[0], params)
+    return _encrypt_rows(pub, m_bits, *draw, params)
 
 
 def decrypt_bits(
@@ -123,14 +134,31 @@ def unpack_bits_u32(words: jax.Array, nbits: int) -> jax.Array:
     )
 
 
+def _kem_draw(key: jax.Array, params: RLWEParams):
+    """One encapsulation's randomness: the message bits m and the
+    encryption's r, e1, e2, each (1, n)."""
+    kb, ke = jax.random.split(key)
+    m = jax.random.bernoulli(kb, 0.5, (1, params.n)).astype(jnp.int32)
+    return (m,) + _encrypt_draw(ke, 1, params)
+
+
 def kem_encapsulate(pub: PublicKey, key: jax.Array, params: RLWEParams = RLWEParams()):
     """Returns (Ciphertext, shared_key (8,) uint32 = 256 bits)."""
-    n, q, k = params
-    kb, ke = jax.random.split(key)
-    m = jax.random.bernoulli(kb, 0.5, (1, n)).astype(jnp.int32)
-    ct = encrypt_bits(pub, m, ke, params)
-    shared = pack_bits_u32(m[0])
-    return ct, shared
+    m, r, e1, e2 = _kem_draw(key, params)
+    return _encrypt_rows(pub, m, r, e1, e2, params), pack_bits_u32(m[0])
+
+
+def kem_encapsulate_many(
+    pub: PublicKey, keys: jax.Array, params: RLWEParams = RLWEParams()
+):
+    """``kem_encapsulate`` for each of V keys, bit for bit: returns
+    (Ciphertext of (V, 1, n) rows, shared keys (V, 8)).  Each key's draw
+    is the singular one under ``vmap``; the ring products then run once
+    over all V rows."""
+    m, r, e1, e2 = (x[:, 0] for x in jax.vmap(
+        lambda k: _kem_draw(k, params))(keys))
+    ct = _encrypt_rows(pub, m, r, e1, e2, params)
+    return Ciphertext(ct.c1[:, None], ct.c2[:, None]), pack_bits_u32(m)
 
 
 def kem_decapsulate(

@@ -58,3 +58,9 @@ LOST_CSDS = "lifecycle.lost_csds"              # gauge
 # --------------------------------------------------------------- kernels
 FUSED_LAUNCHES = "kernels.fused_launches"      # counter: fused dispatch groups
 FUSED_STRIPES = "kernels.fused_stripes"        # counter: stripes batched
+# the seal dispatch's RLWE encapsulations, one jitted program a chunk of
+# rows (``core/archival/pipeline.py``): programs launched, real sessions,
+# and the dummy rows that pad a chunk to its fixed shape
+KEM_LAUNCHES = "kem.launches"                  # counter
+KEM_SESSIONS = "kem.sessions"                  # counter
+KEM_PADDED = "kem.padded"                      # counter

@@ -11,7 +11,14 @@ from repro.kernels.polymul.ops import polymul, polymul_fixed
 from repro.kernels.polymul.polymul import negacyclic_matmul_pallas
 from repro.core.crypto import rlwe
 from repro.core.crypto.chacha import chacha20_block, keystream, xor_stream
-from repro.core.crypto.hybrid import bytes_to_u32, seal, u32_to_bytes, unseal
+from repro.core.crypto.hybrid import (
+    bytes_to_u32,
+    encapsulate_session,
+    encapsulate_sessions,
+    seal,
+    u32_to_bytes,
+    unseal,
+)
 from repro.core.crypto.rsa_baseline import (
     rsa_decrypt_blocks,
     rsa_encrypt_blocks,
@@ -258,6 +265,48 @@ def test_hybrid_roundtrip_property(data, seed):
     block = seal(pub, words, jax.random.PRNGKey(seed + 1))
     got = unseal(s, block)
     assert u32_to_bytes(got, len(data)) == data
+
+
+# session counts V of one seal dispatch, as shards per stripe: one shard,
+# one partial stripe, full and partial stripes mixed (14), one full
+# program (16), and more than one program's rows (37, as a drain makes)
+KEM_LAYOUTS = [[1], [3], [4, 4, 4, 2], [4, 4, 4, 4], [4] * 9 + [1]]
+
+
+@pytest.mark.parametrize(
+    "layout", KEM_LAYOUTS, ids=lambda l: f"V{sum(l)}"
+)
+def test_batched_kem_matches_singular(layout):
+    """The seal dispatch's batched KEM (``encapsulate_sessions`` and the
+    pipeline's chunked program over it) gives, shard for shard, what
+    ``encapsulate_session`` gives for ``fold_in(stripe key, shard)``, and
+    every shard's ciphertext decapsulates to its session key."""
+    from repro.core.archival.pipeline import _encapsulate_stripes
+
+    params = rlwe.RLWEParams()
+    pub, s = rlwe.keygen(jax.random.PRNGKey(21), params)
+    stripe_keys = [jax.random.fold_in(jax.random.PRNGKey(22), i)
+                   for i in range(len(layout))]
+    shard_keys = [jax.random.fold_in(k, sh)
+                  for k, S in zip(stripe_keys, layout) for sh in range(S)]
+    want = [encapsulate_session(pub, k, params) for k in shard_keys]
+    rows = encapsulate_sessions(pub, jnp.stack(shard_keys), params)
+    per_stripe = _encapsulate_stripes(pub, stripe_keys, layout, params)
+    assert [len(ms) for ms in per_stripe] == layout
+    flat = [m for ms in per_stripe for m in ms]
+    assert len(flat) == len(want) == sum(layout)
+    for v, (w, got) in enumerate(zip(want, flat)):
+        for name in w._fields:
+            a, b = np.asarray(getattr(got, name)), np.asarray(getattr(w, name))
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_array_equal(a, b, err_msg=name)
+            np.testing.assert_array_equal(
+                np.asarray(getattr(rows, name)[v]), b, err_msg=name
+            )
+        opened = rlwe.kem_decapsulate(
+            s, rlwe.Ciphertext(got.kem_c1, got.kem_c2), params
+        )
+        np.testing.assert_array_equal(np.asarray(opened), np.asarray(w.session))
 
 
 # ---------------------------------------------------------------- RSA baseline

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh
 
+from repro.core.archival import pipeline
 from repro.core.archival.pipeline import (
     ArchiveConfig,
     StripeArchive,
@@ -222,21 +223,60 @@ def test_seal_payload_stripe_default_is_fused_and_identical_to_chained():
 
 
 def test_seal_payload_stripes_matches_singular():
+    """Batches of equal stripes, and of full and partial stripes mixed
+    (their sessions share one KEM program), seal as each stripe alone."""
     cfg = ArchiveConfig(codec=CFG)
     pub, _ = rlwe.keygen(jax.random.PRNGKey(1))
-    stripes = [_payloads(40 + i, [3000 + 7 * i, 2999]) for i in range(3)]
-    manifests = [
-        [{"n_i8": int(f.shape[0])} for f in fl] for fl in stripes
-    ]
-    keys = [jax.random.PRNGKey(100 + i) for i in range(3)]
-    plural = seal_payload_stripes(pub, stripes, manifests, keys, cfg)
-    for got, fl, mf, k in zip(plural, stripes, manifests, keys):
-        want = seal_payload_stripe(pub, fl, mf, k, cfg)
-        for bg, bw in zip(got.blocks, want.blocks):
-            assert _eq(bg.sealed.body, bw.sealed.body)
-            assert bg.manifest == bw.manifest
-        assert _eq(got.parity["p"], want.parity["p"])
-        assert _eq(got.parity["q"], want.parity["q"])
+    for layout in ([2, 2, 2], [4, 1, 3, 4, 2]):
+        stripes = [
+            _payloads(40 + i, [3000 + 7 * i + 5 * s for s in range(S)])
+            for i, S in enumerate(layout)
+        ]
+        manifests = [
+            [{"n_i8": int(f.shape[0])} for f in fl] for fl in stripes
+        ]
+        keys = [jax.random.PRNGKey(100 + i) for i in range(len(layout))]
+        plural = seal_payload_stripes(pub, stripes, manifests, keys, cfg)
+        for got, fl, mf, k in zip(plural, stripes, manifests, keys):
+            want = seal_payload_stripe(pub, fl, mf, k, cfg)
+            assert len(got.blocks) == len(want.blocks) == len(fl)
+            for bg, bw in zip(got.blocks, want.blocks):
+                assert _eq(bg.sealed.body, bw.sealed.body)
+                assert _eq(bg.sealed.kem_c1, bw.sealed.kem_c1)
+                assert _eq(bg.sealed.kem_c2, bw.sealed.kem_c2)
+                assert _eq(bg.sealed.nonce, bw.sealed.nonce)
+                assert bg.manifest == bw.manifest
+            assert _eq(got.parity["p"], want.parity["p"])
+            assert _eq(got.parity["q"], want.parity["q"])
+
+
+def test_kem_program_built_once_for_every_dispatch_size():
+    """Every session count a dispatch can hold (1 to 16: up to four
+    stripes of up to four shards) runs the one KEM program, so the
+    warm-up's launches build it and none is built in a measured window;
+    the per-stripe key stacks vary only with the shard count."""
+    cfg = ArchiveConfig(codec=CFG)
+    pub, _ = rlwe.keygen(jax.random.PRNGKey(2))
+    payload = jnp.zeros(64, jnp.int8)
+    pipeline._encapsulate_rows._clear_cache()
+    pipeline._stack._clear_cache()
+    launched = []
+
+    def no_launch(stripes, keys_a, nonces_a, **kw):
+        launched.append([k.shape for k in keys_a])
+
+    for V in range(1, 17):
+        layout = [4] * (V // 4) + ([V % 4] if V % 4 else [])
+        pipeline.seal_payload_stripes_dispatch(
+            pub, [[payload] * S for S in layout],
+            [[{"n_i8": 64}] * S for S in layout],
+            [jax.random.PRNGKey(V * 10 + i) for i in range(len(layout))],
+            cfg, fused_dispatch_fn=no_launch,
+        )
+        assert launched[-1] == [(S, 8) for S in layout]
+    assert pipeline._encapsulate_rows._cache_size() == 1
+    # sessions and nonces, each for 1, 2, 3 and 4 shards
+    assert pipeline._stack._cache_size() == 8
 
 
 # --------------------------------------------------- read side: fused-written

@@ -431,7 +431,9 @@ def served_backlog(tmp_path_factory):
         committed += fe.drain()
         events = list(OBS.tracer.events)
         counters = {k: OBS.metrics.get(k) for k in (
-            obs_names.ING_DISPATCHED_GOPS, obs_names.ING_DISPATCH_WAIT_US)}
+            obs_names.ING_DISPATCHED_GOPS, obs_names.ING_DISPATCH_WAIT_US,
+            obs_names.KEM_LAUNCHES, obs_names.KEM_SESSIONS,
+            obs_names.KEM_PADDED)}
     obs.reset()
     assert not fe.shed_log
     return events, counters, committed
@@ -468,3 +470,17 @@ def test_dispatched_gops_count_the_gops_sealed(served_backlog):
     assert sealed == 22
     assert counters[obs_names.ING_DISPATCHED_GOPS] == sealed
     assert counters[obs_names.ING_DISPATCH_WAIT_US] > 0
+
+
+def test_kem_counters_count_real_and_padded_rows(served_backlog):
+    """One KEM program a dispatch (two stripes of up to four shards fit in
+    one): its real rows are the GOPs sealed, the rest of its fixed rows
+    padding."""
+    from repro.core.archival.pipeline import KEM_ROWS
+
+    events, counters, committed = served_backlog
+    sealed = sum(len(st.blocks) for st in committed)
+    launches = counters[obs_names.KEM_LAUNCHES]
+    assert launches == len([e for e in events if e["name"] == "ingest.kem"])
+    assert counters[obs_names.KEM_SESSIONS] == sealed == 22
+    assert counters[obs_names.KEM_PADDED] == KEM_ROWS * launches - sealed

@@ -125,3 +125,28 @@ def test_motion_search_compiles(one_chip):
         _spec(one_chip, (720 + 2 * block, 1280 + 2 * radius), jnp.int32),
     )
     assert _launches(hlo, "motion_search") == 1
+
+
+def test_kem_program_compiles(one_chip, monkeypatch):
+    """The seal dispatch's KEM program: a chunk of RLWE encapsulations with
+    one ring-product kernel for each of the public key's polynomials."""
+    from repro.core.archival import pipeline
+    from repro.core.crypto import rlwe
+    from repro.kernels.polymul import ops as polymul_ops
+
+    # the kernel picks interpret mode from the attached backend (the CPU
+    # here): lower it as on the chip, and keep that trace from later tests
+    monkeypatch.setattr(polymul_ops, "_use_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        params = rlwe.RLWEParams()
+        vec = _spec(one_chip, (params.n,), jnp.int32)
+        compiled = pipeline._encapsulate_rows.lower(
+            rlwe.PublicKey(vec, vec),
+            [_spec(one_chip, (2,), jnp.uint32)] * pipeline.KEM_ROWS,
+            _spec(one_chip, (pipeline.KEM_ROWS,), jnp.uint32),
+            params=params,
+        ).compile()
+    finally:
+        jax.clear_caches()
+    assert _launches(compiled.as_text(), "polymul_fixed") == 2
