@@ -337,7 +337,7 @@ def _stripes_equal(a, b) -> bool:
 
 def phase_sharded(dep: Deployment, seed: int, ctx: Dict, gop_bytes: int):
     """--chips 4: the sealed stripes of a 4-device mesh equal one
-    device's."""
+    device's, and data shard s of each is kept on mesh device s."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -356,10 +356,17 @@ def phase_sharded(dep: Deployment, seed: int, ctx: Dict, gop_bytes: int):
     for i, (a, b) in enumerate(zip(on_mesh, on_one)):
         check(_stripes_equal(a, b), f"stripe {i} differs between the mesh "
               "and one device")
+        # data shard s of a stripe is kept by mesh device s alone
+        for s, blk in enumerate(a.blocks):
+            check(blk.sealed.body.devices() == {devices[s]},
+                  f"stripe {i}: shard {s}'s body is on "
+                  f"{sorted(d.id for d in blk.sealed.body.devices())}, "
+                  f"not on device {devices[s].id} alone")
     gops = sum(len(st.blocks) for st in on_one)
     log(f"sharded: {len(on_one)} stripes ({gops} GOPs) bit-identical on a "
-        f"4-device mesh and on one device; wall {t_mesh:.1f} s (mesh) and "
-        f"{t_one:.1f} s (one device), compilation included")
+        f"4-device mesh and on one device, each body on its shard's device; "
+        f"wall {t_mesh:.1f} s (mesh) and {t_one:.1f} s (one device), "
+        f"compilation included")
 
 
 class PhaseClock:

@@ -115,6 +115,9 @@ exactly ONE site so the totals conserve:
 * ``ingest.device_to_journal`` — sealed body bytes leaving the kernel for
   the journal (the only payload traffic the CSD design ships host-side).
 * ``ingest.shard_to_parity`` — P/Q strip bytes per sealed stripe.
+* ``ingest.cross_chip`` — bytes a mesh write launch moves between chips
+  (the parity partials its reduce gathers); billed in
+  ``distributed/archival.entropy_seal_sharded``.
 * ``replay.read`` — sealed bytes a restore actually moved (present wanted
   shards); ``replay.parity`` — degraded-read amplification (surviving
   unwanted peers + parity strips fed to ``recover_stripe``); both billed

@@ -44,13 +44,15 @@ log2(max_rows) regardless of the GOP-size mix.
 from __future__ import annotations
 
 import functools
+import math
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
@@ -80,6 +82,7 @@ from repro.kernels.fused.entropy_seal import entropy_seal_pallas
 from repro.kernels.seal import ops as seal_ops
 from repro.kernels.seal import ref as _ref
 from repro.obs import (
+    EDGE_CROSS_CHIP,
     EDGE_REBUILD_READ,
     EDGE_REBUILD_WRITE,
     Metrics,
@@ -237,24 +240,33 @@ def unseal_stripe_sharded(stripe: SealedStripe, keys, nonces, *, mesh: Mesh,
 
 # ------------------------------------------------ sharded fused archival
 @functools.lru_cache(maxsize=None)
-def _sharded_fused_core(mesh: Mesh, axis: str, s_loc: int, parity: str,
+def _mesh_write_program(mesh: Mesh, axis: str, n_shards: int, parity: str,
                         use_pallas: bool, interpret: bool):
-    """jit'd shard_map'd fused entropy+seal core, cached per (mesh,
-    local shard count, mode).
+    """The fused entropy+seal write program over ``mesh``: one jitted
+    program, cached per (mesh, stripe width, mode) and built once per
+    (stripes, rows) bucket.
 
-    Inputs arrive regrouped as (K, S_pad, ...) — stripes on axis 0, stripe
-    shards on axis 1, the SHARD axis partitioned over the mesh (the CSD-
-    array mapping: mesh shard d compresses and seals the stripe shards it
-    owns).  Each mesh shard flattens its local (K, s_loc, ...) slice back
-    onto the kernel batch axis and runs the fused entropy+seal kernel
-    exactly ONCE — launches/stripe-batch/device = 1 covering rANS + pack +
+    Codes arrive placed, (K, S', T, 128): stripes on axis 0, stripe shards
+    on axis 1 (S' the width padded to a multiple of the mesh axis), the
+    SHARD axis split over the mesh — the CSD-array mapping: mesh shard d
+    compresses and seals the stripe shards it owns.  The raw lengths,
+    session keys, nonces and GF(256) Q coefficients arrive flat (B, ...)
+    on every chip; the program regroups and pads them, and each chip keeps
+    its own rows.  Dummy shards get ``n_valid = 0``, which raw-skips them to
+    zero stored bytes, so sealed rows and parity partials are unperturbed.
+
+    Each mesh shard flattens its local (K, S'/D) slice onto the kernel
+    batch axis and runs the fused kernels exactly ONCE — rANS + pack +
     raw-skip + ChaCha20 + local partial P/Q.  The only cross-shard traffic
     is the XOR reduce of the per-stripe parity partials (exact, order-free
-    — bit-identical to the single-device launch); GF(256) Q coefficients
-    ride in as operands carrying the *global* shard index, so Q partials
-    are globally correct before the reduce.
+    — bit-identical to the single-device launch); Q coefficients carry the
+    *global* shard index, so Q partials are globally correct before the
+    reduce.  Sealed rows and word counts stay on the chips that made them,
+    (K, S', ...) split over the mesh; P and Q come out on every chip.
     """
     D = int(mesh.shape[axis])
+    s_pad = -(-n_shards // D) * D
+    s_loc = s_pad // D
     with_p = parity != "none"
     with_q = parity == "raid6"
 
@@ -281,53 +293,93 @@ def _sharded_fused_core(mesh: Mesh, axis: str, s_loc: int, parity: str,
         return tuple(outs)
 
     n_extra = int(with_p) + int(with_q)
-    fn = _shard_map(
+    local = _shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, axis),) * 5,
         out_specs=(P(None, axis), P(None, axis)) + (P(),) * n_extra,
     )
-    return jax.jit(fn)
+
+    def _fused_core_mesh(codes, n_valid, keys, nonces, q_coef):
+        K = codes.shape[0]
+
+        def regroup(a):
+            a = a.reshape((K, n_shards) + a.shape[1:])
+            pad = [(0, 0), (0, s_pad - n_shards)] + [(0, 0)] * (a.ndim - 2)
+            return jnp.pad(a, pad)
+
+        return local(codes, *(regroup(a)
+                              for a in (n_valid, keys, nonces, q_coef)))
+
+    return jax.jit(_fused_core_mesh)
+
+
+def _cross_chip_bytes(x, sharding) -> int:
+    """Bytes that placing ``x`` on ``sharding`` copies between devices:
+    each device's part of the target that the device does not already hold.
+    A host array is staged from the host and moves nothing between them."""
+    if not isinstance(x, jax.Array):
+        return 0
+    held = x.sharding.devices_indices_map(x.shape)
+    n = 0
+    for dev, want in sharding.devices_indices_map(x.shape).items():
+        want = [range(*w.indices(d)) for w, d in zip(want, x.shape)]
+        have = [range(*h.indices(d))
+                for h, d in zip(held.get(dev, ()), x.shape)]
+        if have and all(h.start <= w.start and w.stop <= h.stop
+                        for w, h in zip(want, have)):
+            continue
+        n += x.dtype.itemsize * math.prod(len(w) for w in want)
+    return n
 
 
 def entropy_seal_sharded(codes, n_valid, keys, nonces, q_coef, *,
                          mesh: Mesh, axis: str = "data", n_shards: int,
                          parity: str = "raid6", use_pallas: bool = True,
                          interpret: Optional[bool] = None):
-    """Sharded twin of the fused write core (same array outputs).
+    """Sharded twin of the fused write core: the ``core_fn`` of
+    ``fused_ops.entropy_seal_stripes`` (bake ``mesh``/``axis`` with
+    ``functools.partial``; the batching layer supplies the remaining static
+    config as keyword arguments).
 
-    Drop-in ``core_fn`` for ``fused_ops.entropy_seal_stripes`` (bake
-    ``mesh``/``axis`` with ``functools.partial``; the batching layer
-    supplies the remaining static config as keyword arguments).  Stripe
-    shard counts that do not divide the mesh axis are padded with dummy
-    zero shards — ``n_valid = 0`` raw-skips them to zero stored bytes, so
-    sealed rows and parity partials are unperturbed.
+    ``codes`` is the launch's (B, T, 128) host array, stripe shard s of
+    stripe k at row k*n_shards + s, as the one-device core takes it.  Each
+    stripe shard's rows go from the host straight to the chip that owns
+    it, one transfer per chip; the small per-shard arrays go to every chip.
+    Returns sealed rows (K, S', R_cap, 128) and word counts (K, S', 1),
+    each split over the mesh along its shard axis, and P, Q (K, R_cap, 128)
+    on every chip (None where ``parity`` has none).
+
+    With telemetry on, the bytes the launch moves between chips are billed
+    here, at the one site: what placing its inputs copies from one chip to
+    another (nothing of the codes, which come from the host) and the
+    parity partials the reduce gathers — each chip receives the other
+    D - 1 chips' partial of every strip.
     """
     B = codes.shape[0]
     K = B // n_shards
     D = int(mesh.shape[axis])
     s_pad = -(-n_shards // D) * D
-
-    def regroup(a):
-        a = a.reshape((K, n_shards) + a.shape[1:])
-        if s_pad == n_shards:
-            return a
-        pad = [(0, 0), (0, s_pad - n_shards)] + [(0, 0)] * (a.ndim - 2)
-        return jnp.pad(a, pad)
-
-    core = _sharded_fused_core(
-        mesh, axis, s_pad // D, parity, use_pallas, use_interpret(interpret)
+    grouped = np.asarray(codes).reshape((K, n_shards) + codes.shape[1:])
+    if s_pad != n_shards:
+        grouped = np.pad(grouped, [(0, 0), (0, s_pad - n_shards)]
+                         + [(0, 0)] * (grouped.ndim - 2))
+    program = _mesh_write_program(
+        mesh, axis, n_shards, parity, use_pallas, use_interpret(interpret)
     )
-    outs = core(*(regroup(a) for a in (codes, n_valid, keys, nonces, q_coef)))
-    sealed = outs[0][:, :n_shards].reshape((B,) + outs[0].shape[2:])
-    n_words = outs[1][:, :n_shards].reshape(B, 1)
-    i = 2
-    p = q = None
-    if parity != "none":
-        p = outs[i]
-        i += 1
-    if parity == "raid6":
-        q = outs[i]
+    placed = NamedSharding(mesh, P(None, axis))
+    everywhere = NamedSharding(mesh, P())
+    small = (n_valid, keys, nonces, q_coef)
+    outs = program(jax.device_put(grouped, placed),
+                   *(jax.device_put(a, everywhere) for a in small))
+    sealed, n_words = outs[:2]
+    p = outs[2] if parity != "none" else None
+    q = outs[3] if parity == "raid6" else None
+    if OBS.enabled:
+        moved = sum(_cross_chip_bytes(a, everywhere) for a in small)
+        moved += D * (D - 1) * sum(x.nbytes for x in (p, q) if x is not None)
+        OBS.count(obs_names.MESH_CROSS_CHIP_BYTES, moved)
+        OBS.flow(EDGE_CROSS_CHIP, moved)
     return sealed, n_words, p, q
 
 
@@ -924,8 +976,6 @@ def _rebuild_shard_body(
         _, p, _ = seal_ops.unseal_stripe(
             packed, zero_k, zero_n, parity="raid5", use_pallas=use_pallas,
         )
-    import numpy as np
-
     from repro.core.crypto.hybrid import SealedBlock
     from repro.core.archival.pipeline import ArchivedBlock
 

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
-    "use_interpret", "as_payload_list", "stack_rows", "host_prefixes",
-    "le_words",
+    "use_interpret", "as_payload_list", "host_rows", "stack_rows",
+    "axis_devices", "host_prefixes", "le_words",
 ]
 
 
@@ -54,17 +54,38 @@ def as_payload_list(payloads) -> List[jax.Array]:
     return [arr[s].reshape(-1).astype(jnp.int8) for s in range(arr.shape[0])]
 
 
+def host_rows(flats: Sequence, rows: int, width: int = 128,
+              dtype=np.uint32) -> np.ndarray:
+    """Ragged flat arrays -> one zero-padded (S, rows, width) host array."""
+    host = np.zeros((len(flats), rows * width), dtype)
+    for s, f in enumerate(flats):
+        v = np.asarray(f).reshape(-1).view(dtype)
+        host[s, : v.shape[0]] = v
+    return host.reshape(len(flats), rows, width)
+
+
 def stack_rows(flats: Sequence, rows: int, width: int = 128,
                dtype=np.uint32) -> jax.Array:
     """Ragged flat arrays -> one zero-padded (S, rows, width) device array.
 
     Staged on the host with one transfer: a device-side pad per ragged
     length would compile a program per distinct size."""
-    host = np.zeros((len(flats), rows * width), dtype)
-    for s, f in enumerate(flats):
-        v = np.asarray(f).reshape(-1).view(dtype)
-        host[s, : v.shape[0]] = v
-    return jax.device_put(host.reshape(len(flats), rows, width))
+    return jax.device_put(host_rows(flats, rows, width, dtype))
+
+
+def axis_devices(arr: jax.Array, axis: int) -> Optional[List]:
+    """The device holding each index of ``arr`` along ``axis``, where the
+    array is split along it over several devices; None for an array that
+    one device holds."""
+    sharding = arr.sharding
+    if len(sharding.device_set) == 1:
+        return None
+    n = arr.shape[axis]
+    owners: List = [None] * n
+    for dev, idx in sharding.devices_indices_map(arr.shape).items():
+        for i in range(*idx[axis].indices(n)):
+            owners[i] = dev
+    return owners
 
 
 def host_prefixes(arr, lengths: Sequence[int]) -> List[np.ndarray]:

@@ -22,11 +22,15 @@ Words past a shard's stored length are zero by kernel masking, so the
 slice is exact.
 
 ``core_fn`` overrides the fused launch itself — it is called with the
-same arrays plus the launch's static config as keyword arguments
+launch's codes as a host array (it stages them itself), the other arrays,
+and the launch's static config as keyword arguments
 (``n_shards``/``parity``/``use_pallas``/``interpret``, since
 ``n_shards`` varies per batch group); the sharded path
 (``repro.distributed.archival``) passes a shard_map'd wrapper, exactly
-like the ``core_fn`` seams of the entropy and seal ops.
+like the ``core_fn`` seams of the entropy and seal ops.  A core may
+return its sealed rows and word counts per stripe and shard, (K, S', ...)
+with the shard axis split over a mesh: the finalize tail then slices each
+stripe on the chips that hold it and keeps each body on its shard's chip.
 
 Pipelined submission: the wrapper is split at the single device→host
 sync point (the rANS word-count fetch — the ``encode_payloads``
@@ -50,7 +54,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.archival.raid import gf_pow_gen
-from repro.kernels import as_payload_list, stack_rows, use_interpret
+from repro.kernels import (
+    as_payload_list,
+    axis_devices,
+    host_rows,
+    use_interpret,
+)
 from repro.obs import OBS, names as obs_names
 from repro.kernels.entropy.ops import HEADER_BYTES, MAX_ROWS, rows_for
 from repro.kernels.entropy.rans import N_LANES, STREAM_VERSION
@@ -75,8 +84,10 @@ class _PendingGroup(NamedTuple):
     S: int                # shards per stripe
     T: int                # padded lane rows of the launch
     n_raw: List[int]      # raw payload bytes, group-flat (len(idxs) * S)
-    sealed: jax.Array     # lazy (len(idxs)*S, T', 128) sealed rows
-    n_words_rans: jax.Array  # lazy per-shard rANS word counts
+    # lazy sealed rows: (len(idxs)*S, T', 128) on one device, or
+    # (len(idxs), S', T', 128) with the shard axis split over a mesh
+    sealed: jax.Array
+    n_words_rans: jax.Array  # lazy per-shard rANS word counts, same layout
     p: Optional[jax.Array]
     q: Optional[jax.Array]
 
@@ -111,6 +122,12 @@ def _fused_core(codes, n_valid, keys, nonces, q_coef, *, n_shards: int,
         codes, n_valid, keys, nonces, q_coef, n_shards=n_shards,
         parity=parity,
     )
+
+
+def _fused_launch(codes, *args, **kw):
+    """The one-device launch: the host's codes in one transfer, then the
+    fused program."""
+    return _fused_core(jax.device_put(codes), *args, **kw)
 
 
 def entropy_seal_stripes_dispatch(
@@ -175,10 +192,10 @@ def entropy_seal_stripes_dispatch(
         OBS.count(obs_names.FUSED_LAUNCHES)
         OBS.count(obs_names.FUSED_STRIPES, len(idxs))
         # host staging of the launch's inputs and the launch call itself
-        with OBS.span("kernels.stage", rows=T, stripes=len(idxs)):
+        with OBS.span("kernels.stage", rows=T, stripes=len(idxs)) as sp:
             flats = [p for i in idxs for p in plists[i]]
             n_raw = [int(f.shape[0]) for f in flats]
-            codes = stack_rows(flats, T, N_LANES, np.int8)
+            codes = host_rows(flats, T, N_LANES, np.int8)
             n_valid = jnp.asarray(n_raw, jnp.int32).reshape(-1, 1)
             keys_a = jnp.concatenate([
                 jnp.asarray(keys[i], jnp.uint32).reshape(S, 8) for i in idxs
@@ -188,11 +205,13 @@ def entropy_seal_stripes_dispatch(
             ])
             coefs = [gf_pow_gen(s) for s in range(S)]
             q_coef = jnp.asarray(coefs * len(idxs), jnp.uint32).reshape(-1, 1)
-            fn = core_fn or _fused_core
+            fn = core_fn or _fused_launch
             sealed, n_words_rans, p, q = fn(
                 codes, n_valid, keys_a, nonces_a, q_coef, n_shards=S,
                 parity=parity, use_pallas=use_pallas, interpret=interp,
             )
+            if OBS.enabled:
+                sp.set(chips=len(sealed.sharding.device_set))
         out_groups.append(
             _PendingGroup(idxs, S, T, n_raw, sealed, n_words_rans, p, q)
         )
@@ -211,14 +230,15 @@ def entropy_seal_stripes_finalize(
         S, T = g.S, g.T
         # the host waits here for the launch to finish
         with OBS.span("kernels.fetch", stripes=len(g.idxs)):
-            nw_host = [int(w) for w in
-                       np.asarray(g.n_words_rans).reshape(-1)]
+            nw_host = np.asarray(g.n_words_rans).reshape(len(g.idxs), -1)
+        on_mesh = g.sealed.ndim == 4
+        owners = axis_devices(g.sealed, 1) if on_mesh else None
         for j, i in enumerate(g.idxs):
             off = j * S
             metas, stored_words, stored_len = [], [], []
             for s in range(S):
                 nr = g.n_raw[off + s]
-                nc = HEADER_BYTES + 2 * nw_host[off + s]
+                nc = HEADER_BYTES + 2 * int(nw_host[j, s])
                 if nc >= nr:
                     metas.append(
                         {"codec": "rans", "version": STREAM_VERSION,
@@ -234,12 +254,19 @@ def entropy_seal_stripes_finalize(
                 stored_words.append(-(-nc // 4))
             rows_of = bucket_rows_for if pr_list[i] is not None else pad_rows_for
             R = rows_of(max(stored_words))
+            if on_mesh:
+                # each chip slices the shards it holds; the host takes them,
+                # and each body goes back to its own chip
+                sealed = np.asarray(g.sealed[j, :, :R])[:S]
+            else:
+                sealed = g.sealed[off:off + S, :R]
             stripe = SealedStripe(
-                g.sealed[off:off + S, :R],
+                sealed,
                 g.p[j, :R] if g.p is not None else None,
                 g.q[j, :R] if g.q is not None else None,
                 tuple(stored_words),
                 tuple(stored_len),
+                tuple(owners[:S]) if owners else None,
             )
             results[i] = (stripe, metas)
     return results

@@ -48,20 +48,26 @@ class SealedStripe(NamedTuple):
     q: Optional[jax.Array]       # (R, 128) uint32 RAID-6 parity (or None)
     n_words: Tuple[int, ...]     # valid uint32 words per shard
     n_i8: Tuple[int, ...]        # valid int8 payload bytes per shard
+    # the device that keeps each shard's body (a mesh launch: shard s on the
+    # chip that sealed it); None keeps every body on the default device
+    devices: Optional[Tuple] = None
 
     def body(self, s: int) -> jax.Array:
         """Exact-length flat uint32 sealed body of shard s."""
         return self.sealed[s].reshape(-1)[: self.n_words[s]]
 
     def bodies(self) -> List[jax.Array]:
-        """Exact-length flat bodies of every shard, as device arrays.
+        """Exact-length flat bodies of every shard, as device arrays, each
+        on its shard's device.
 
         Sliced on the host from ONE fetch of the stripe (the bodies are on
         their way to the journal anyway): a device slice per ragged length
         would compile a program per GOP size, which on a TPU costs more than
         the whole seal."""
         host = np.asarray(self.sealed).reshape(self.sealed.shape[0], -1)
-        return [jax.device_put(host[s, :n]) for s, n in enumerate(self.n_words)]
+        devices = self.devices or (None,) * len(self.n_words)
+        return [jax.device_put(host[s, :n], d)
+                for s, (n, d) in enumerate(zip(self.n_words, devices))]
 
     @property
     def pad_words(self) -> int:

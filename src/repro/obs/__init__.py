@@ -45,6 +45,7 @@ from typing import Dict
 
 from .ledger import (  # noqa: F401  (re-exported surface)
     ByteLedger,
+    EDGE_CROSS_CHIP,
     EDGE_DEVICE_TO_JOURNAL,
     EDGE_ENTROPY_COMP,
     EDGE_ENTROPY_RAW,
@@ -70,7 +71,8 @@ __all__ = [
     "Tracer", "Span", "NullSpan", "NULL_SPAN",
     "ByteLedger", "names",
     "EDGE_HOST_TO_DEVICE", "EDGE_ENTROPY_RAW", "EDGE_ENTROPY_COMP",
-    "EDGE_DEVICE_TO_JOURNAL", "EDGE_SHARD_TO_PARITY", "EDGE_INGEST_SHED",
+    "EDGE_DEVICE_TO_JOURNAL", "EDGE_SHARD_TO_PARITY", "EDGE_CROSS_CHIP",
+    "EDGE_INGEST_SHED",
     "EDGE_REPLAY_PLANNED", "EDGE_REPLAY_FULL_BASELINE",
     "EDGE_REPLAY_READ", "EDGE_REPLAY_PARITY",
     "EDGE_SCRUB_READ", "EDGE_SCRUB_SYNDROME",

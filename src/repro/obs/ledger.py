@@ -21,6 +21,10 @@ edge                        billed by / meaning
                             journal (compressed + sealed: the only payload
                             traffic the CSD design ships)
 ``ingest.shard_to_parity``  P/Q parity strip bytes per sealed stripe
+``ingest.cross_chip``       bytes a mesh write launch moves between chips
+                            (``distributed/archival.entropy_seal_sharded``):
+                            the parity partials the XOR reduce gathers, and
+                            inputs placed off the chip that held them
 ``ingest.shed``             payload bytes the streaming admission controller
                             refused under queue pressure
                             (``serving/ingest.StreamIngestFrontend._shed``,
@@ -61,6 +65,7 @@ __all__ = [
     "EDGE_ENTROPY_COMP",
     "EDGE_DEVICE_TO_JOURNAL",
     "EDGE_SHARD_TO_PARITY",
+    "EDGE_CROSS_CHIP",
     "EDGE_INGEST_SHED",
     "EDGE_REPLAY_PLANNED",
     "EDGE_REPLAY_FULL_BASELINE",
@@ -77,6 +82,7 @@ EDGE_ENTROPY_RAW = "ingest.entropy_raw"
 EDGE_ENTROPY_COMP = "ingest.entropy_comp"
 EDGE_DEVICE_TO_JOURNAL = "ingest.device_to_journal"
 EDGE_SHARD_TO_PARITY = "ingest.shard_to_parity"
+EDGE_CROSS_CHIP = "ingest.cross_chip"
 EDGE_INGEST_SHED = "ingest.shed"
 EDGE_REPLAY_PLANNED = "replay.planned"
 EDGE_REPLAY_FULL_BASELINE = "replay.full_baseline"

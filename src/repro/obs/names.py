@@ -64,3 +64,10 @@ FUSED_STRIPES = "kernels.fused_stripes"        # counter: stripes batched
 KEM_LAUNCHES = "kem.launches"                  # counter
 KEM_SESSIONS = "kem.sessions"                  # counter
 KEM_PADDED = "kem.padded"                      # counter
+
+# ------------------------------------------------------------------ mesh
+# bytes a mesh write launch moves between chips: its inputs placed off the
+# chip that held them, and the parity partials the reduce gathers
+# (``distributed/archival.entropy_seal_sharded``, the one site; its ledger
+# edge is ``ingest.cross_chip``)
+MESH_CROSS_CHIP_BYTES = "mesh.cross_chip_bytes"  # counter

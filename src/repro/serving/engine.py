@@ -39,6 +39,7 @@ key material is recycled.  All of it shows up in ``stats()``.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, NamedTuple, Optional
 
@@ -58,10 +59,12 @@ from repro.core.archival.scrub import StripeScrubber, retire_stripes
 from repro.core.csd.retrieval import ReadPlan, plan_retrieval
 from repro.distributed.archival import (
     StripeCoalescer,
+    entropy_decode_sharded,
     plan_rebuild,
     rebuild_csd_sharded,
     seal_coalesced_stripes_dispatch,
     seal_coalesced_stripes_finalize,
+    unseal_stripe_sharded,
 )
 from repro.models.config import ModelConfig
 from repro.models.transformer import decode_step, init_cache
@@ -393,11 +396,20 @@ class ArchiveIngest:
         """Read a retained stripe back: unseal + entropy-decode the shard
         subset a plan names (``plan.shards_by_stripe[stripe_id]``; None =
         every shard, parity-verified) with the RLWE secret ``s``.  Lost
-        shards are rebuilt from parity on the way.  Returns (codec
+        shards are rebuilt from parity on the way.  With a mesh, the
+        unseal and decode run shard_map'd over it.  Returns (codec
         payloads, their blocks) in ``shards`` order."""
+        mesh_fns = {}
+        if self.mesh is not None:
+            mesh_fns = dict(
+                unseal_fn=functools.partial(
+                    unseal_stripe_sharded, mesh=self.mesh, axis=self.axis),
+                entropy_decode_fn=functools.partial(
+                    entropy_decode_sharded, mesh=self.mesh, axis=self.axis),
+            )
         return restore_stripe_payloads(
             s, self._stripes[stripe_id], self.cfg.archive, shards=shards,
-            manifests=self._manifests[stripe_id],
+            manifests=self._manifests[stripe_id], **mesh_fns,
         )
 
     # ------------------------------------------------------ durability tier

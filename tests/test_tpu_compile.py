@@ -20,8 +20,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+import _hlo
 
 T = 32768           # coder rows of a 720p GOP shard (4 MiB of codes)
 S = 4               # data shards per stripe
@@ -30,7 +33,7 @@ HBM_BYTES = 16 * 10**9
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -46,9 +49,14 @@ def one_chip():
         )
     except Exception as e:  # pragma: no cover - needs the TPU library
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_on)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -150,3 +158,48 @@ def test_kem_program_compiles(one_chip, monkeypatch):
     finally:
         jax.clear_caches()
     assert _launches(compiled.as_text(), "polymul_fixed") == 2
+
+
+def test_mesh_write_program_compiles(topo):
+    """The four-CSD write program on a 2x2 mesh: one launch of each write
+    kernel per chip over its own shard of K = 4 stripes, the codes placed
+    shard by shard, and the parity partials' all-gather the only
+    collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed.archival import _mesh_write_program
+
+    K = 4
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    placed = NamedSharding(mesh, P(None, "data"))
+    everywhere = NamedSharding(mesh, P())
+    u32 = jnp.uint32
+    program = _mesh_write_program(mesh, "data", S, "raid6", True, False)
+    compiled = program.lower(
+        _spec(placed, (K, S, T, 128), jnp.int8),
+        _spec(everywhere, (K * S, 1), jnp.int32),
+        _spec(everywhere, (K * S, 8), u32),
+        _spec(everywhere, (K * S, 3), u32),
+        _spec(everywhere, (K * S, 1), u32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, f"{used} bytes do not fit one v5e chip"
+    hlo = compiled.as_text()
+    for name in ("rans_histogram", "rans_encode", "seal_stripes"):
+        assert _launches(hlo, name) == 1, name
+    ops = set(re.findall(r"= \S+ ([a-z-]+)\(", hlo))
+    assert {"all-gather", "all-gather-start"} & ops, ops
+    assert not {"all-reduce", "all-to-all", "collective-permute",
+                "reduce-scatter"} & ops, ops
+    # the only collectives are the P and Q partials' all-gathers: each chip
+    # gives its (K, R, 128) words of one strip, and receives the other
+    # three chips' -- nothing of the codes or the sealed rows crosses
+    D = len(topo.devices)
+    gathers = _hlo.all_gathers(hlo)
+    assert len(gathers) == 2, gathers
+    for g in gathers:
+        assert g.dtype == "u32" and g.group == D, g
+        assert _hlo.squeezed(g.operand) == (K, R, 128), g
+    assert _hlo.received_bytes(hlo, D) == D * (D - 1) * 2 * K * R * 128 * 4
