@@ -320,8 +320,13 @@ def restore_gop(
 
 
 # ------------------------------------------------------------ batched stripe
-def _u32_rows_to_u8(rows: jax.Array) -> jax.Array:
-    """(R, 128) uint32 parity tile -> flat uint8 (R*512,)."""
+def _u32_rows_to_u8(rows: jax.Array, sharding=None) -> jax.Array:
+    """(R, 128) uint32 parity tile -> flat uint8 (R*512,) on the device.
+    Host rows (a fused launch's, cut on the host) are viewed as bytes there
+    and placed with ``sharding`` by one transfer, so no device program
+    queues behind a launch in flight; device rows are bitcast in place."""
+    if isinstance(rows, np.ndarray):
+        return jax.device_put(rows.view(np.uint8).reshape(-1), sharding)
     return jax.lax.bitcast_convert_type(rows, jnp.uint8).reshape(-1)
 
 
@@ -485,9 +490,11 @@ def _assemble_stripe(stripe, mats, manifests: List[Dict]) -> StripeArchive:
     ]
     parity = None
     if stripe.p is not None:
-        parity = {"p": _u32_rows_to_u8(stripe.p), "pad_to": stripe.pad_words}
+        at = stripe.parity_sharding
+        parity = {"p": _u32_rows_to_u8(stripe.p, at),
+                  "pad_to": stripe.pad_words}
         if stripe.q is not None:
-            parity["q"] = _u32_rows_to_u8(stripe.q)
+            parity["q"] = _u32_rows_to_u8(stripe.q, at)
     if OBS.enabled:
         _bill_ingest(stripe, manifests, parity)
     return StripeArchive(blocks, parity)

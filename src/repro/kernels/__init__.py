@@ -20,7 +20,7 @@ import numpy as np
 
 __all__ = [
     "use_interpret", "as_payload_list", "host_rows", "stack_rows",
-    "axis_devices", "host_prefixes", "le_words",
+    "axis_devices", "host_blocks", "host_prefixes", "le_words",
 ]
 
 
@@ -86,6 +86,21 @@ def axis_devices(arr: jax.Array, axis: int) -> Optional[List]:
         for i in range(*idx[axis].indices(n)):
             owners[i] = dev
     return owners
+
+
+def host_blocks(arr: jax.Array, axis: int) -> List[np.ndarray]:
+    """``arr`` on the host as the blocks its devices hold along ``axis``, in
+    order along it: each device's own host copy, not one array assembled
+    from them.  Each is read from the array object whose host copy
+    ``arr.copy_to_host_async()`` started: ``arr`` itself where every device
+    holds all of it (one device), else its per-device shards."""
+    if arr.is_fully_replicated:
+        return [np.asarray(arr)]
+    first = {}
+    for shard in arr.addressable_shards:
+        start = shard.index[axis].indices(arr.shape[axis])[0]
+        first.setdefault(start, shard.data)
+    return [np.asarray(first[k]) for k in sorted(first)]
 
 
 def host_prefixes(arr, lengths: Sequence[int]) -> List[np.ndarray]:

@@ -29,19 +29,24 @@ and the launch's static config as keyword arguments
 (``repro.distributed.archival``) passes a shard_map'd wrapper, exactly
 like the ``core_fn`` seams of the entropy and seal ops.  A core may
 return its sealed rows and word counts per stripe and shard, (K, S', ...)
-with the shard axis split over a mesh: the finalize tail then slices each
-stripe on the chips that hold it and keeps each body on its shard's chip.
+with the shard axis split over a mesh: each body then goes back to its
+shard's chip.
 
 Pipelined submission: the wrapper is split at the single device→host
 sync point (the rANS word-count fetch — the ``encode_payloads``
 single-fetch pattern).  ``entropy_seal_stripes_dispatch`` does all host
-prep and fires the jitted launches WITHOUT blocking — the returned
-:class:`PendingSeal` holds lazy device arrays — and
+prep, fires the jitted launches WITHOUT blocking — the returned
+:class:`PendingSeal` holds lazy device arrays — and starts each launch's
+sealed rows, P and Q on their way to the host;
 ``entropy_seal_stripes_finalize`` performs the blocking fetch plus the
-host-side manifest/slicing tail.  ``entropy_seal_stripes`` is exactly
-``finalize(dispatch(...))``, so a caller that overlaps host prep for
-batch k+1 with batch k's in-flight launch (``repro.serving.ingest``'s
-two-slot submit ring) produces bit-identical archives by construction.
+host-side manifest tail, and cuts each stripe from those host copies.
+The finalize enqueues no device program: one would queue behind the next
+batch's launch, which a pipelined caller has already dispatched, and the
+host blocks on it once the device's in-flight programs run out.
+``entropy_seal_stripes`` is exactly ``finalize(dispatch(...))``, so a
+caller that overlaps host prep for batch k+1 with batch k's in-flight
+launch (``repro.serving.ingest``'s two-slot submit ring) produces
+bit-identical archives by construction.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ from repro.core.archival.raid import gf_pow_gen
 from repro.kernels import (
     as_payload_list,
     axis_devices,
+    host_blocks,
     host_rows,
     use_interpret,
 )
@@ -65,7 +71,12 @@ from repro.kernels.entropy.ops import HEADER_BYTES, MAX_ROWS, rows_for
 from repro.kernels.entropy.rans import N_LANES, STREAM_VERSION
 from repro.kernels.fused import ref as _ref
 from repro.kernels.fused.entropy_seal import entropy_seal_pallas
-from repro.kernels.seal.ops import SealedStripe, bucket_rows_for, pad_rows_for
+from repro.kernels.seal.ops import (
+    HostRows,
+    SealedStripe,
+    bucket_rows_for,
+    pad_rows_for,
+)
 
 __all__ = [
     "entropy_seal_stripe",
@@ -84,8 +95,9 @@ class _PendingGroup(NamedTuple):
     S: int                # shards per stripe
     T: int                # padded lane rows of the launch
     n_raw: List[int]      # raw payload bytes, group-flat (len(idxs) * S)
-    # lazy sealed rows: (len(idxs)*S, T', 128) on one device, or
-    # (len(idxs), S', T', 128) with the shard axis split over a mesh
+    # lazy outputs, already on their way to the host.  Sealed rows:
+    # (len(idxs)*S, T', 128) on one device, or (len(idxs), S', T', 128)
+    # with the shard axis split over a mesh
     sealed: jax.Array
     n_words_rans: jax.Array  # lazy per-shard rANS word counts, same layout
     p: Optional[jax.Array]
@@ -96,10 +108,11 @@ class PendingSeal(NamedTuple):
     """A dispatched-but-not-fetched ``entropy_seal_stripes`` batch.
 
     Every launch in ``groups`` is already in flight (jax dispatch is
-    async); the only remaining work is the device→host word-count fetch
-    and the host-side slicing, which ``entropy_seal_stripes_finalize``
-    performs.  Holding one of these while preparing the next batch is the
-    whole double-buffering contract.
+    async), and so is the copy of its outputs to the host; the only
+    remaining work is the device→host word-count fetch and the host-side
+    cut, which ``entropy_seal_stripes_finalize`` performs.  Holding one of
+    these while preparing the next batch is the whole double-buffering
+    contract.
     """
 
     n_stripes: int
@@ -212,6 +225,12 @@ def entropy_seal_stripes_dispatch(
             )
             if OBS.enabled:
                 sp.set(chips=len(sealed.sharding.device_set))
+        # before anything else is enqueued behind this launch; on a mesh
+        # each chip sends its own shards, and one chip P and Q
+        for out in (sealed, p, q):
+            if out is not None:
+                out.copy_to_host_async()
+                OBS.count(obs_names.ROWS_TO_HOST_BYTES, out.nbytes)
         out_groups.append(
             _PendingGroup(idxs, S, T, n_raw, sealed, n_words_rans, p, q)
         )
@@ -222,17 +241,24 @@ def entropy_seal_stripes_finalize(
     pending: PendingSeal,
 ) -> List[Tuple[SealedStripe, List[Dict]]]:
     """Blocking tail of a dispatched batch: fetch the rANS word counts
-    (the ONLY device→host sync) and slice every stripe back to the
-    chained path's row count.  Idempotence is not needed — call once."""
+    (the ONLY wait on the launch) and cut every stripe and its parity,
+    from the host copies of the launch's outputs that its dispatch
+    started, to the chained path's row count.  Idempotence is not needed —
+    call once."""
     results: List = [None] * pending.n_stripes
     pr_list = pending.pr_list
     for g in pending.groups:
-        S, T = g.S, g.T
-        # the host waits here for the launch to finish
-        with OBS.span("kernels.fetch", stripes=len(g.idxs)):
-            nw_host = np.asarray(g.n_words_rans).reshape(len(g.idxs), -1)
-        on_mesh = g.sealed.ndim == 4
-        owners = axis_devices(g.sealed, 1) if on_mesh else None
+        S, T, K = g.S, g.T, len(g.idxs)
+        shard_axis = g.sealed.ndim - 3
+        # the host waits here for the launch to finish, then for the copies
+        # of its outputs: each chip's block of shards as (K, S'/chips, T',
+        # 128), and one copy of P and Q
+        with OBS.span("kernels.fetch", stripes=K):
+            nw_host = np.asarray(g.n_words_rans).reshape(K, -1)
+            blocks = [b.reshape((K, -1) + b.shape[-2:])
+                      for b in host_blocks(g.sealed, shard_axis)]
+            p, q = (None if a is None else np.asarray(a) for a in (g.p, g.q))
+        owners = axis_devices(g.sealed, shard_axis)
         for j, i in enumerate(g.idxs):
             off = j * S
             metas, stored_words, stored_len = [], [], []
@@ -254,19 +280,17 @@ def entropy_seal_stripes_finalize(
                 stored_words.append(-(-nc // 4))
             rows_of = bucket_rows_for if pr_list[i] is not None else pad_rows_for
             R = rows_of(max(stored_words))
-            if on_mesh:
-                # each chip slices the shards it holds; the host takes them,
-                # and each body goes back to its own chip
-                sealed = np.asarray(g.sealed[j, :, :R])[:S]
-            else:
-                sealed = g.sealed[off:off + S, :R]
+            # one block is cut in place; a mesh's blocks are joined
+            parts = [b[j, :, :R] for b in blocks]
+            rows = parts[0] if len(parts) == 1 else np.concatenate(parts)
             stripe = SealedStripe(
-                sealed,
-                g.p[j, :R] if g.p is not None else None,
-                g.q[j, :R] if g.q is not None else None,
+                rows[:S].view(HostRows),
+                None if p is None else p[j, :R].view(HostRows),
+                None if q is None else q[j, :R].view(HostRows),
                 tuple(stored_words),
                 tuple(stored_len),
                 tuple(owners[:S]) if owners else None,
+                None if g.p is None else g.p.sharding,
             )
             results[i] = (stripe, metas)
     return results

@@ -31,8 +31,10 @@ from repro.kernels.seal.seal import (
     seal_stripe_pallas,
     unseal_stripe_pallas,
 )
+from repro.obs import OBS
 
 __all__ = [
+    "HostRows",
     "SealedStripe",
     "seal_stripe",
     "unseal_stripe",
@@ -42,7 +44,19 @@ __all__ = [
 ]
 
 
+class HostRows(np.ndarray):
+    """Sealed rows or parity held on the host: a fused launch's, cut from
+    the copy of its outputs that its dispatch started.  ``at`` is
+    ``jax.Array``'s functional update, applied to a device copy, so a
+    stripe's rows are edited the same way wherever they are held."""
+
+    @property
+    def at(self):
+        return jnp.asarray(self).at
+
+
 class SealedStripe(NamedTuple):
+    # device arrays, or HostRows where a fused launch's finalize cut them
     sealed: jax.Array            # (S, R, 128) uint32, zero-padded tails
     p: Optional[jax.Array]       # (R, 128) uint32 RAID-5 parity (or None)
     q: Optional[jax.Array]       # (R, 128) uint32 RAID-6 parity (or None)
@@ -51,6 +65,9 @@ class SealedStripe(NamedTuple):
     # the device that keeps each shard's body (a mesh launch: shard s on the
     # chip that sealed it); None keeps every body on the default device
     devices: Optional[Tuple] = None
+    # where P and Q go when they are host rows (a mesh launch: every chip);
+    # None: the default device
+    parity_sharding: Optional[jax.sharding.Sharding] = None
 
     def body(self, s: int) -> jax.Array:
         """Exact-length flat uint32 sealed body of shard s."""
@@ -60,14 +77,15 @@ class SealedStripe(NamedTuple):
         """Exact-length flat bodies of every shard, as device arrays, each
         on its shard's device.
 
-        Sliced on the host from ONE fetch of the stripe (the bodies are on
-        their way to the journal anyway): a device slice per ragged length
-        would compile a program per GOP size, which on a TPU costs more than
-        the whole seal."""
-        host = np.asarray(self.sealed).reshape(self.sealed.shape[0], -1)
-        devices = self.devices or (None,) * len(self.n_words)
-        return [jax.device_put(host[s, :n], d)
-                for s, (n, d) in enumerate(zip(self.n_words, devices))]
+        Cut on the host: a fused launch's rows are there already
+        (``HostRows``), so nothing is fetched; device rows are fetched once.
+        A device slice per ragged length would compile a program per GOP
+        size, which on a TPU costs more than the whole seal."""
+        with OBS.span("kernels.slice", stripes=1):
+            host = np.asarray(self.sealed).reshape(self.sealed.shape[0], -1)
+            devices = self.devices or (None,) * len(self.n_words)
+            return [jax.device_put(host[s, :n], d)
+                    for s, (n, d) in enumerate(zip(self.n_words, devices))]
 
     @property
     def pad_words(self) -> int:

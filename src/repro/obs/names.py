@@ -58,6 +58,10 @@ LOST_CSDS = "lifecycle.lost_csds"              # gauge
 # --------------------------------------------------------------- kernels
 FUSED_LAUNCHES = "kernels.fused_launches"      # counter: fused dispatch groups
 FUSED_STRIPES = "kernels.fused_stripes"        # counter: stripes batched
+# bytes of sealed rows and parity a fused launch sends to the host, the
+# copies started at its dispatch (``kernels/fused/ops.py``, billed once per
+# launch group)
+ROWS_TO_HOST_BYTES = "kernels.rows_to_host_bytes"  # counter
 # the seal dispatch's RLWE encapsulations, one jitted program a chunk of
 # rows (``core/archival/pipeline.py``): programs launched, real sessions,
 # and the dummy rows that pad a chunk to its fixed shape

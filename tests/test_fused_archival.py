@@ -11,6 +11,10 @@ multi-device job does) to exercise all of {1, 2, 4, 8}.
 """
 
 import functools
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import jax
@@ -40,7 +44,7 @@ from repro.kernels.entropy import ops as eops
 from repro.kernels.entropy.ops import rows_for
 from repro.kernels.entropy.rans import N_LANES
 from repro.kernels.fused import ops as fops
-from repro.kernels.fused.entropy_seal import entropy_seal_pallas
+from repro.kernels.fused.entropy_seal import entropy_seal_pallas, seal_rows_cap
 from repro.kernels.seal import ops as sops
 
 CFG = CodecConfig(n_layers=2, latent_ch=4, feat_ch=16, mv_cond_ch=4)
@@ -398,6 +402,146 @@ def test_seal_coalesced_stripes_sharded_end_to_end(d):
     assert len(out) == len(sharded[0].blocks)
 
 
+# ------------------------------------------------- the finalize's host cut
+class _NoDeviceIndexing:
+    """A launch's output that refuses any device-side indexing: the
+    finalize may only read it whole, from the host copy its dispatch
+    started."""
+
+    def __init__(self, rows):
+        self._rows = rows
+
+    def __getitem__(self, index):
+        raise AssertionError(f"device-side slice of a launch output: {index}")
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._rows, dtype)
+
+    def __getattr__(self, name):
+        return getattr(self._rows, name)
+
+
+def _host_cut_seen(d: int) -> dict:
+    """Three stripes of four shards (one launch group) through dispatch and
+    a finalize whose pending rows and parity refuse device slices, on one
+    device (``d == 1``) or split over a mesh of ``d``: what the finalize
+    returned against the chained ``encode_payloads -> seal_stripe`` path,
+    where the bodies and the stored parity went, the early copy's billed
+    bytes against the hand count K * (S + 2) * R_cap * 128 * 4 (rows, P
+    and Q), and the ``kernels.slice`` spans."""
+    from repro import obs
+    from repro.obs import OBS, names as obs_names
+
+    devices = jax.devices()[:d]
+    core = None if d == 1 else functools.partial(
+        entropy_seal_sharded, mesh=Mesh(np.array(devices), ("data",)),
+        axis="data")
+    lens = [4000, 3999, 4001, 128]
+    stripes = [_payloads(60 + k, lens, raw_shards=(3,)) for k in range(3)]
+    keys, nonces = zip(*(_session(60 + k, len(lens)) for k in range(3)))
+    obs.disable()
+    obs.reset()
+    with obs.enabled():
+        pending = fops.entropy_seal_stripes_dispatch(
+            stripes, keys, nonces, core_fn=core)
+        counted = OBS.metrics.get(obs_names.ROWS_TO_HOST_BYTES)
+        pending = pending._replace(groups=[
+            g._replace(sealed=_NoDeviceIndexing(g.sealed),
+                       p=_NoDeviceIndexing(g.p), q=_NoDeviceIndexing(g.q))
+            for g in pending.groups])
+        got = fops.entropy_seal_stripes_finalize(pending)
+        bodies = [st.bodies() for st, _ in got]
+        parity = [[pipeline._u32_rows_to_u8(x, st.parity_sharding)
+                   for x in (st.p, st.q)] for st, _ in got]
+        events = list(OBS.tracer.events)
+    obs.reset()
+    by_id = {e["id"]: e for e in events}
+
+    def under_fetch(e):
+        while e["parent"]:
+            e = by_id[e["parent"]]
+            if e["name"] == "kernels.fetch":
+                return True
+        return False
+
+    identical = True
+    for (st, metas), bs, pq, p, k, n in zip(got, bodies, parity, stripes,
+                                            keys, nonces):
+        want, want_metas = _chained(p, k, n, "raid6")
+        identical &= (metas == want_metas and st.n_words == want.n_words
+                      and _eq(st.sealed, want.sealed)
+                      and _eq(st.p, want.p) and _eq(st.q, want.q)
+                      and all(_eq(b, want.body(s)) for s, b in enumerate(bs))
+                      and _eq(pq[0], pipeline._u32_rows_to_u8(want.p))
+                      and _eq(pq[1], pipeline._u32_rows_to_u8(want.q)))
+    T = rows_for(max(lens))
+    slices = [e for e in events if e["name"] == "kernels.slice"]
+    return {
+        "groups": len(pending.groups),
+        "identical": bool(identical),
+        "placement": [[sorted(x.id for x in b.devices()) for b in bs]
+                      for bs in bodies],
+        "parity_placement": [list(ids) for ids in sorted(
+            {tuple(sorted(x.id for x in a.devices()))
+             for pq in parity for a in pq})],
+        "device_ids": [x.id for x in devices],
+        "counted": int(counted),
+        "hand_count": (len(stripes) * (len(lens) + 2) * seal_rows_cap(T)
+                       * 128 * 4),
+        "slice_stripes": [e["attrs"].get("stripes") for e in slices],
+        "slices_under_fetch": sum(under_fetch(e) for e in slices),
+    }
+
+
+@pytest.fixture(scope="module", params=["one_device", "mesh4"])
+def host_cut(request):
+    """``_host_cut_seen`` on one device here, or on a mesh of four in a
+    child process with four CPU devices (the device count is fixed when
+    JAX starts, and this process has one)."""
+    if request.param == "one_device":
+        return _host_cut_seen(1)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_platform"
+                        "_device_count=4").strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p])
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "4"], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_finalize_cuts_stripes_on_the_host(host_cut):
+    """The finalize slices no sealed rows or parity on the device, and
+    still returns every stripe, body and stored parity bit-identical to the
+    chained path; on the mesh each body is back on its shard's device, and
+    P and Q on every device, as the launch left them."""
+    seen = host_cut
+    assert seen["groups"] == 1
+    assert seen["identical"]
+    ids = seen["device_ids"]
+    for st in seen["placement"]:
+        assert st == [[ids[s % len(ids)]] for s in range(len(st))]
+    assert seen["parity_placement"] == [sorted(ids)]
+
+
+def test_rows_to_host_bytes_and_slice_spans(host_cut):
+    """The early copy bills K * (S + 2) * R_cap * 128 * 4 bytes for the
+    launch (each chip's shards once on the mesh, and one copy of P and Q),
+    and every ``kernels.slice`` span carries ``stripes=`` and sits outside
+    the ``kernels.fetch`` wait."""
+    seen = host_cut
+    assert seen["counted"] == seen["hand_count"]
+    assert seen["slice_stripes"] and all(seen["slice_stripes"])
+    assert sum(seen["slice_stripes"]) == 3
+    assert seen["slices_under_fetch"] == 0
+
+
 # ------------------------------------------------------------------ hygiene
 def test_hygiene_sweep_covers_fused_sources():
     """The TPU-hostile-construct bans apply to the fused kernel package:
@@ -407,3 +551,7 @@ def test_hygiene_sweep_covers_fused_sources():
     srcs = _kernel_sources()
     for want in ("entropy_seal.py", "ref.py", "ops.py"):
         assert any(p.endswith("fused/" + want) for p in srcs), want
+
+
+if __name__ == "__main__":
+    print(json.dumps(_host_cut_seen(int(sys.argv[1]))))

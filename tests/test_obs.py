@@ -396,6 +396,7 @@ WRITE_PATH_SPANS = {
     "kernels.stage": "ingest.seal",
     "ingest.commit": None,
     "kernels.fetch": "ingest.commit",
+    "kernels.slice": "ingest.commit",
     "ingest.journal": "ingest.commit",
 }
 
