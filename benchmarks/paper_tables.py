@@ -198,9 +198,7 @@ def fig8_fig9_codec(quick: bool = True) -> List[Row]:
     from repro.core.codec.training import (
         CodecTrainConfig, codec_pretrain_step, codec_train_step, init_codec_trainer,
     )
-    from repro.train.optimizer import adamw_init
-
-    from repro.train.optimizer import AdamWConfig
+    from repro.core.codec.optimizer import AdamWConfig, adamw_init
 
     rows: List[Row] = []
     cfg = CodecConfig(n_layers=3, latent_ch=6, feat_ch=16, mv_cond_ch=4)
@@ -210,7 +208,7 @@ def fig8_fig9_codec(quick: bool = True) -> List[Row]:
     # quick mode is rate-limited by the 1-core CPU host: PSNR here is the
     # *reduced-scale* operating point (~28-31 dB); BENCH_FULL trains longer.
     pre_steps = 60 if quick else 400
-    opt_all = adamw_init(params, tcfg.opt)
+    opt_all = adamw_init(params)
     for i in range(pre_steps):
         clips = smooth_clip(jax.random.PRNGKey(100 + i), t=3)
         params, opt_all, m = codec_pretrain_step(params, opt_all, tcfg, clips)

@@ -1,4 +1,4 @@
-"""Minimal parameterized NN primitives shared by the codec and model zoo.
+"""Minimal parameterized NN primitives for the codec.
 
 Plain pytrees of arrays + pure functions (no flax/haiku dependency): params are
 nested dicts, apply functions are jit/pjit-friendly and shard_map-safe.
@@ -7,7 +7,6 @@ nested dicts, apply functions are jit/pjit-friendly and shard_map-safe.
 from __future__ import annotations
 
 import math
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -17,10 +16,6 @@ __all__ = [
     "conv2d",
     "init_conv_transpose",
     "conv2d_transpose",
-    "init_dense",
-    "dense",
-    "layer_norm",
-    "rms_norm",
 ]
 
 _DN = ("NHWC", "HWIO", "NHWC")
@@ -67,37 +62,3 @@ def conv2d_transpose(params, x, stride=2, padding="SAME"):
         dimension_numbers=_DN,
     )
     return y + params["b"].astype(x.dtype)
-
-
-def init_dense(key, din, dout, dtype=jnp.float32, bias=True):
-    scale = 1.0 / math.sqrt(din)
-    p = {"w": jax.random.uniform(key, (din, dout), dtype, -scale, scale)}
-    if bias:
-        p["b"] = jnp.zeros((dout,), dtype)
-    return p
-
-
-def dense(params, x):
-    y = x @ params["w"].astype(x.dtype)
-    if "b" in params:
-        y = y + params["b"].astype(x.dtype)
-    return y
-
-
-def layer_norm(x, gamma=None, beta=None, eps=1e-5):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    if gamma is not None:
-        y = y * gamma.astype(x.dtype)
-    if beta is not None:
-        y = y + beta.astype(x.dtype)
-    return y
-
-
-def rms_norm(x, gamma, eps=1e-6):
-    # reduce in f32 for stability regardless of activation dtype
-    xf = x.astype(jnp.float32)
-    ms = (xf * xf).mean(-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(ms + eps)
-    return (y * gamma.astype(jnp.float32)).astype(x.dtype)

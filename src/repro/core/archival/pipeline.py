@@ -90,9 +90,9 @@ The stripe's shard axis IS the paper's CSD array: shard s of a stripe lives
 on storage device s, and the whole point of the CSD offload is that each
 device seals *its own* shard locally while only the tiny parity reduction
 crosses devices.  On the TPU adaptation the ``data`` mesh axis plays the
-CSD-array role (see ``distributed/sharding.py``): ``repro.distributed.
-archival`` shard_maps the fused entropy+seal kernel over ``data`` so every
-mesh shard runs one local kernel launch on its slice of the stripe, then
+CSD-array role: ``repro.distributed.archival`` shard_maps the fused
+entropy+seal kernel over ``data`` so every mesh shard runs one local
+kernel launch on its slice of the stripe, then
 combines RAID-5 P / RAID-6 Q with a cross-shard XOR reduce (exact, order-
 free, bit-identical to this module's single-device path).  The hooks below
 (``encode_gop_payload`` / ``seal_payload_stripe`` / the ``fused_fn`` /

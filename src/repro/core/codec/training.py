@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codec.layered_codec import CodecConfig, encode_gop
-from repro.train.optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update
+from repro.core.codec.optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update
 
 __all__ = ["CodecTrainConfig", "codec_loss", "make_codec_train_step", "init_codec_trainer"]
 

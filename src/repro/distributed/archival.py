@@ -5,10 +5,9 @@ Sharded archival (mesh axis <-> CSD array):
 Salient Store's headline wins come from running compression/encryption/
 parity *where the shards live*, in parallel across the CSD array, so only
 parity-sized traffic crosses the interconnect.  On the TPU adaptation the
-``data`` mesh axis is the designated storage-shard axis (see
-``distributed/sharding.py``): mesh shard d owns stripe shards
-``s % D == d``-style contiguous slices, exactly as CSD d owns its disks'
-stripes in the paper.  ``seal_stripe_sharded`` shard_maps the fused Pallas
+``data`` mesh axis is the designated storage-shard axis: mesh shard d
+owns stripe shards ``s % D == d``-style contiguous slices, exactly as CSD
+d owns its disks' stripes in the paper.  ``seal_stripe_sharded`` shard_maps the fused Pallas
 seal kernel (``repro.kernels.seal``) over that axis:
 
   * each mesh shard runs ONE local kernel launch over its (S/D, R, 512)

@@ -1,22 +1,13 @@
-"""Batched serving engine: slot-based continuous batching over decode_step,
-plus the edge server's camera-facing archive ingest tier.
+"""The edge server's camera-facing archive ingest tier (``ArchiveIngest``).
 
-LM serving: requests occupy fixed batch slots; each engine step decodes one
-token for every active slot (padded slots run but are masked).  Prefill uses
-the full forward to populate KV/SSM caches token-by-token (teacher-forcing
-path — the same code the parity tests validate), so serve results match
-training-side semantics exactly.
-
-Archive ingest (``ArchiveIngest``): the continuous-learning edge server also
-*serves* N camera streams pushing ragged GOPs.  Ingest mirrors the LM
-engine's batching idea at the storage layer: GOPs are codec-encoded on
-arrival, coalesced across streams into full parity stripes
-(``StripeCoalescer``), and each completed stripe is entropy-coded by the
-on-device interleaved-rANS kernel and sealed, one fused launch per stage —
-shard_map'd over the storage mesh's ``data`` axis when a mesh is attached,
-so every mesh shard codes + seals its local slice (the CSD-array mapping;
-see ``repro.distributed.archival``).  ``IngestConfig.archive.codec_name``
-falls back to the host zstd/zlib codec for compatibility.
+The continuous-learning edge server *serves* N camera streams pushing
+ragged GOPs.  GOPs are codec-encoded on arrival, coalesced across streams
+into full parity stripes (``StripeCoalescer``), and each batch of completed
+stripes is entropy-coded by the on-device interleaved-rANS kernel and
+sealed, one fused launch per row bucket; with a mesh attached, each chip
+codes and seals its own shards (the CSD-array mapping; see
+``repro.distributed.archival``).  ``IngestConfig.archive.codec_name`` falls
+back to the host zstd/zlib codec for compatibility.
 
 The ingest tier also fronts the archive's READ side: every sealed stripe is
 indexed into a :class:`StripeCatalog` with the per-GOP salience descriptors
@@ -44,7 +35,6 @@ import time
 from typing import Dict, List, NamedTuple, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.archival.catalog import StripeCatalog, gop_descriptors
@@ -66,121 +56,12 @@ from repro.distributed.archival import (
     seal_coalesced_stripes_finalize,
     unseal_stripe_sharded,
 )
-from repro.models.config import ModelConfig
-from repro.models.transformer import decode_step, init_cache
 from repro.obs import Metrics, OBS
 from repro.obs import names as obs_names
 
-__all__ = [
-    "ServeConfig",
-    "Request",
-    "ServingEngine",
-    "IngestConfig",
-    "ArchiveIngest",
-]
+__all__ = ["IngestConfig", "ArchiveIngest"]
 
 
-class ServeConfig(NamedTuple):
-    max_batch: int = 4
-    max_len: int = 64
-    greedy: bool = True
-
-
-class Request(NamedTuple):
-    rid: int
-    prompt: List[int]
-    max_new: int
-
-
-class _Slot(NamedTuple):
-    rid: int
-    pos: int
-    remaining: int
-    tokens: List[int]
-
-
-class ServingEngine:
-    def __init__(self, params, cfg: ModelConfig, scfg: ServeConfig, frontend=None):
-        self.params = params
-        self.cfg = cfg
-        self.scfg = scfg
-        self.frontend = frontend
-        self.cache = init_cache(
-            params, cfg, scfg.max_batch, scfg.max_len, frontend=frontend
-        )
-        self.slots: List[Optional[_Slot]] = [None] * scfg.max_batch
-        self.queue: List[Request] = []
-        self.finished: Dict[int, List[int]] = {}
-        self._step = jax.jit(
-            lambda p, t, c, pos: decode_step(p, cfg, t, c, pos)
-        )
-
-    # ------------------------------------------------------------ admin
-    def submit(self, req: Request):
-        self.queue.append(req)
-
-    def _admit(self):
-        for i in range(self.scfg.max_batch):
-            if self.slots[i] is None and self.queue:
-                req = self.queue.pop(0)
-                # prefill: feed prompt tokens one at a time into slot i's cache
-                for t, tok in enumerate(req.prompt[:-1]):
-                    self._feed(i, tok, t)
-                self.slots[i] = _Slot(
-                    req.rid,
-                    len(req.prompt) - 1,
-                    req.max_new,
-                    list(req.prompt),
-                )
-
-    def _feed(self, slot: int, token: int, pos: int):
-        toks = jnp.zeros((self.scfg.max_batch, 1), jnp.int32).at[slot, 0].set(token)
-        _, self.cache = self._step(self.params, toks, self.cache, jnp.int32(pos))
-
-    # ------------------------------------------------------------- step
-    def step(self) -> int:
-        """Decode one token for every active slot; returns #active."""
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return 0
-        # NOTE: slots share a positional counter per step in this reference
-        # engine only when their positions coincide; for mixed positions we
-        # step the max-position slot batch-wise and others individually.
-        by_pos: Dict[int, List[int]] = {}
-        for i in active:
-            by_pos.setdefault(self.slots[i].pos, []).append(i)
-        for pos, idxs in sorted(by_pos.items()):
-            toks = jnp.zeros((self.scfg.max_batch, 1), jnp.int32)
-            for i in idxs:
-                toks = toks.at[i, 0].set(self.slots[i].tokens[-1])
-            logits, self.cache = self._step(
-                self.params, toks, self.cache, jnp.int32(pos)
-            )
-            nxt = (
-                jnp.argmax(logits, axis=-1)
-                if self.scfg.greedy
-                else jax.random.categorical(jax.random.PRNGKey(pos), logits)
-            )
-            for i in idxs:
-                s = self.slots[i]
-                tok = int(np.asarray(nxt)[i])
-                tokens = s.tokens + [tok]
-                if s.remaining <= 1 or s.pos + 2 >= self.scfg.max_len:
-                    self.finished[s.rid] = tokens
-                    self.slots[i] = None
-                else:
-                    self.slots[i] = _Slot(s.rid, s.pos + 1, s.remaining - 1, tokens)
-        return len(active)
-
-    def run_to_completion(self, max_steps: int = 1000) -> Dict[int, List[int]]:
-        for _ in range(max_steps):
-            if self.step() == 0 and not self.queue:
-                break
-        return self.finished
-
-
-# ------------------------------------------------------------ archive ingest
 class IngestConfig(NamedTuple):
     n_shards: int = 4  # GOPs per stripe == storage shards per parity group
     archive: ArchiveConfig = ArchiveConfig()

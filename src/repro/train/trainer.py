@@ -31,9 +31,7 @@ moves), locates corrupt shards by P/Q syndrome and re-commits repaired
 bodies — so silent corruption is found and fixed while training continues,
 not discovered by a failed replay read months later.
 
-Everything is pure JAX + the core modules; the same loop drives the LM path
-through ``lm_train_step`` (distributed/steps.py) with codec-based gradient
-compression as an option.
+Everything is pure JAX + the core modules.
 """
 
 from __future__ import annotations

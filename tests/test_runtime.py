@@ -1,5 +1,4 @@
-"""Runtime tests: checkpoint fault tolerance, grad compression, trainer loop,
-serving engine."""
+"""Runtime tests: checkpoint fault tolerance and the trainer loop."""
 
 import os
 
@@ -9,18 +8,13 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.crypto import rlwe
-from repro.data.tokens import TokenStreamConfig, sample_batch
-from repro.data.video import make_streams, render_clip
-from repro.models.registry import get_smoke_config
-from repro.models.transformer import forward, init_model
-from repro.serving.engine import Request, ServeConfig, ServingEngine
+from repro.data.video import make_streams
 from repro.train.checkpoint import (
     CheckpointError,
     latest_step,
     load_checkpoint,
     save_checkpoint,
 )
-from repro.train.grad_compress import GradCompressConfig, compress_tree, init_state
 from repro.train.trainer import SalientTrainer, TrainerConfig
 
 
@@ -76,36 +70,6 @@ def test_checkpoint_picks_latest(tmp_path):
     _assert_tree_equal(_tree(5), loaded)
 
 
-# -------------------------------------------------------------- grad comp
-def test_grad_compress_accuracy_and_bytes():
-    grads = {
-        "w": jax.random.normal(jax.random.PRNGKey(0), (64, 64)),
-        "b": jax.random.normal(jax.random.PRNGKey(1), (64,)) * 0.01,
-    }
-    st = init_state(grads)
-    out1, st, wire, raw = compress_tree(grads, st, GradCompressConfig(n_layers=1))
-    out2, _, wire2, _ = compress_tree(grads, init_state(grads), GradCompressConfig(n_layers=2))
-    e1 = float(jnp.abs(out1["w"] - grads["w"]).max())
-    e2 = float(jnp.abs(out2["w"] - grads["w"]).max())
-    assert e2 < e1  # progressive layers refine
-    assert int(wire) == (64 * 64 + 64) * 1
-    assert int(wire2) == (64 * 64 + 64) * 2
-    assert int(raw) == (64 * 64 + 64) * 4
-
-
-def test_grad_compress_error_feedback_unbiased():
-    """With error feedback, repeated compression of a constant gradient
-    converges: accumulated output approaches n * g."""
-    g = {"w": jnp.full((32,), 0.37)}
-    st = init_state(g)
-    acc = jnp.zeros((32,))
-    n = 20
-    for _ in range(n):
-        out, st, _, _ = compress_tree(g, st, GradCompressConfig(n_layers=1))
-        acc = acc + out["w"]
-    np.testing.assert_allclose(np.asarray(acc / n), 0.37, rtol=1e-3)
-
-
 # ----------------------------------------------------------------- trainer
 def test_salient_trainer_end_to_end(tmp_path):
     streams = make_streams(4, height=32, width=32)
@@ -132,32 +96,3 @@ def test_trainer_rebalances_on_straggler(tmp_path):
     for i in range(3):
         rep = tr.run_step(shard_times=[8.0, 1.0])
     assert rep.rebalanced or tr.placement.assignment != before
-
-
-# ----------------------------------------------------------------- serving
-def test_serving_engine_matches_forward_greedy():
-    cfg = get_smoke_config("qwen2_0_5b")
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(params, cfg, ServeConfig(max_batch=2, max_len=32))
-    prompt = [3, 5, 7]
-    eng.submit(Request(rid=0, prompt=prompt, max_new=4))
-    out = eng.run_to_completion()[0]
-    assert len(out) == len(prompt) + 4
-
-    # greedy reference: iterative full forward
-    toks = list(prompt)
-    for _ in range(4):
-        logits, _ = forward(params, cfg, jnp.asarray([toks], jnp.int32), q_chunk=0)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    assert out == toks
-
-
-def test_serving_engine_batches_multiple_requests():
-    cfg = get_smoke_config("mamba2_370m")
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(params, cfg, ServeConfig(max_batch=4, max_len=32))
-    for r in range(3):
-        eng.submit(Request(rid=r, prompt=[2 + r, 4 + r], max_new=3))
-    out = eng.run_to_completion()
-    assert set(out) == {0, 1, 2}
-    assert all(len(v) == 5 for v in out.values())
